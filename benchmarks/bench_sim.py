@@ -26,11 +26,14 @@ import time
 import numpy as np
 
 from repro.core import CoreManager
+from repro.launch.compile_cache import enable_compile_cache
 from repro.sim import ExperimentConfig, metrics as metrics_mod
 from repro.sim.cluster import Cluster
 from repro.sim.fleetstate import FleetAgingSettler
 from repro.workloads import get_scenario
 
+HOST_CPU_NOTE = ("host-CPU wall times of the simulator, not chip speed; "
+                 "chip measurements live in PERF_LEDGER.jsonl")
 DEFAULT_OUT = os.path.join(os.path.dirname(__file__), "..",
                            "BENCH_sim.json")
 # --smoke writes elsewhere by default so a CI-scale run can never
@@ -209,15 +212,9 @@ def bench_fleet_scale(smoke: bool = False) -> dict:
                      "completed": res.completed})
     for backend, n, dur in fleet_points:
         cfg = scaled_cfg(n, dur).with_engine("fleet", backend=backend)
-        try:
-            t0 = time.perf_counter()
-            res = run_experiment(cfg)
-            wall = time.perf_counter() - t0
-        except ImportError:                  # jax absent on this host
-            rows.append({"engine": "fleet", "backend": backend,
-                         "n_machines": n, "duration_s": dur,
-                         "skipped": "backend unavailable"})
-            continue
+        t0 = time.perf_counter()
+        res = run_experiment(cfg)
+        wall = time.perf_counter() - t0
         rows.append({"engine": "fleet", "backend": backend,
                      "n_machines": n, "duration_s": dur,
                      "wall_s": round(wall, 4),
@@ -239,6 +236,7 @@ def main() -> None:
                     "--smoke)")
     args = ap.parse_args()
 
+    enable_compile_cache()
     if args.out is None:
         args.out = SMOKE_OUT if args.smoke else DEFAULT_OUT
     if args.smoke:
@@ -250,6 +248,7 @@ def main() -> None:
 
     out = {
         "benchmark": "default-e2e" if not args.smoke else "smoke-e2e",
+        "note": HOST_CPU_NOTE,
         "env": {
             "python": platform.python_version(),
             "numpy": np.__version__,

@@ -27,7 +27,9 @@ import numpy as np
 
 from repro.core.temperature import CState
 
-_EMPTY = np.empty(0, dtype=np.int64)
+
+def _empty_cores() -> np.ndarray:
+    return np.empty(0, dtype=np.int64)
 
 
 def _readonly(a: np.ndarray) -> np.ndarray:
@@ -148,8 +150,8 @@ class IdleCorrection:
     identically regardless.
     """
 
-    to_idle: np.ndarray = _EMPTY
-    to_wake: np.ndarray = _EMPTY
+    to_idle: np.ndarray = dataclasses.field(default_factory=_empty_cores)
+    to_wake: np.ndarray = dataclasses.field(default_factory=_empty_cores)
     cause: str = "policy"
     deferred_wakes: int = 0
 

@@ -1,8 +1,8 @@
 """jit'd public wrapper: pads to the kernel block size, dispatches to the
-Pallas kernel (interpret=True on CPU) or the jnp oracle."""
+Pallas kernel or the jnp oracle. Interpret mode (for hosts without a TPU)
+is the caller's explicit choice, never inferred here."""
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
 from repro.kernels.aging_update.aging_update import BLOCK, aging_update
@@ -10,7 +10,7 @@ from repro.kernels.aging_update.ref import aging_update_ref
 
 
 def advance_fleet(dvth, temp_c, stress, tau, params, use_kernel=True,
-                  interpret=None):
+                  interpret=False):
     """Advance a fleet of cores' dVth. Inputs (N,); returns (N,) f32."""
     dvth = jnp.asarray(dvth, jnp.float32)
     temp_c = jnp.asarray(temp_c, jnp.float32)
@@ -18,8 +18,6 @@ def advance_fleet(dvth, temp_c, stress, tau, params, use_kernel=True,
     tau = jnp.asarray(tau, jnp.float32)
     if not use_kernel:
         return aging_update_ref(dvth, temp_c, stress, tau, params)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     n = dvth.shape[0]
     pad = (-n) % BLOCK
     if pad:

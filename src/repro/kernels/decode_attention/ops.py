@@ -1,7 +1,6 @@
 """Public wrapper for decode_attention: padding + dispatch."""
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
 from repro.kernels.decode_attention.decode_attention import decode_attention
@@ -9,7 +8,7 @@ from repro.kernels.decode_attention.ref import decode_attention_ref_explicit
 
 
 def decode_bhd(q, k_cache, v_cache, pos, *, window=0, use_kernel=True,
-               block_k=512, interpret=None):
+               block_k=512, interpret=False):
     """q: (B,H,D); caches (B,S,Hkv,D); pos () or (B,)."""
     pos = jnp.asarray(pos, jnp.int32)
     if pos.ndim == 0:
@@ -17,8 +16,6 @@ def decode_bhd(q, k_cache, v_cache, pos, *, window=0, use_kernel=True,
     if not use_kernel:
         return decode_attention_ref_explicit(q, k_cache, v_cache, pos,
                                              window=window)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     s = k_cache.shape[1]
     block_k = min(block_k, s)
     pad = (-s) % block_k

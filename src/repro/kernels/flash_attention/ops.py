@@ -1,7 +1,6 @@
 """Public wrapper: layout conversion, padding, kernel/ref dispatch."""
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
 from repro.kernels.flash_attention.flash_attention import flash_attention
@@ -9,7 +8,7 @@ from repro.kernels.flash_attention.ref import flash_attention_ref
 
 
 def attention_bhsd(q, k, v, *, causal=True, window=0, use_kernel=True,
-                   block_q=128, block_k=128, interpret=None):
+                   block_q=128, block_k=128, interpret=False):
     """Flash attention in (B, S, H, D) model layout. GQA-aware."""
     qt = jnp.swapaxes(q, 1, 2)   # (B,H,S,D)
     kt = jnp.swapaxes(k, 1, 2)
@@ -17,8 +16,6 @@ def attention_bhsd(q, k, v, *, causal=True, window=0, use_kernel=True,
     if not use_kernel:
         out = flash_attention_ref(qt, kt, vt, causal=causal, window=window)
         return jnp.swapaxes(out, 1, 2)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     s = qt.shape[2]
     block_q = min(block_q, s)
     block_k = min(block_k, s)

@@ -1,19 +1,16 @@
 """Public wrapper for ssd_scan: padding + dispatch."""
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
 from repro.kernels.ssd_scan.ref import ssd_scan_ref
 from repro.kernels.ssd_scan.ssd_scan import ssd_scan
 
 
-def ssd(x, dt, a_log, b, c, *, chunk=256, use_kernel=True, interpret=None):
+def ssd(x, dt, a_log, b, c, *, chunk=256, use_kernel=True, interpret=False):
     """Chunked SSD. x (B,L,H,P), dt (B,L,H), a_log (H,), b/c (B,L,N)."""
     if not use_kernel:
         return ssd_scan_ref(x, dt, a_log, b, c)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     l = x.shape[1]
     chunk = min(chunk, l)
     pad = (-l) % chunk

@@ -18,6 +18,7 @@ import numpy as np
 from repro.checkpoint import store
 from repro.configs import get_config, get_smoke_config
 from repro.data.pipeline import DataConfig, SyntheticTokens
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import Model
 from repro.training import optimizer as opt_lib
 
@@ -46,6 +47,7 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     model = Model(cfg)
     ocfg = opt_lib.AdamWConfig(lr=args.lr, warmup_steps=min(20, args.steps),

@@ -43,11 +43,14 @@ Two backends share the same functional step:
 * ``backend="jax"`` — the step is compiled with ``jax.lax.scan`` over
   macro periods (an inner scan covers the micro steps); the aging
   settlement routes through ``repro.kernels.aging_update`` — the
-  Pallas kernel on TPU, its jnp oracle elsewhere. float32: fast, NOT
-  bit-exact vs numpy (documented caveat; see ``--help`` epilogs).
+  compiled Pallas kernel on TPU, its jnp oracle elsewhere (the kernel in
+  interpret mode when ``use_kernel=True`` asks for it off-TPU). The
+  engine resolves this once (``FleetEngine.use_kernel`` /
+  ``.interpret``) and reports it in the telemetry ``engine`` event.
+  float32: fast, NOT bit-exact vs numpy (documented caveat; see
+  ``--help`` epilogs).
 
-``backend="auto"`` resolves to jax when importable, else numpy — the
-promotion of the batched aging backend from opt-in to default at scale.
+``backend="auto"`` is the jax backend (jax is a hard dependency).
 
 What the surrogate does NOT model: per-core task placement (stress
 spreads evenly over the active set, so within-machine frequency CV
@@ -59,6 +62,7 @@ the event engine on small configs is pinned with tolerances in
 from __future__ import annotations
 
 import dataclasses
+import time
 from typing import Any
 
 import numpy as np
@@ -115,18 +119,11 @@ _EPS = 1e-12
 
 
 def _resolve_backend(requested: str) -> str:
-    """'auto' promotes the batched jax aging path when available."""
-    if requested == "numpy":
-        return "numpy"
-    if requested == "jax":
-        import jax  # noqa: F401  (raises if genuinely unavailable)
-        return "jax"
+    """'auto' is the jax backend; 'numpy' the float64 reference."""
+    if requested in ("numpy", "jax"):
+        return requested
     if requested == "auto":
-        try:
-            import jax  # noqa: F401
-            return "jax"
-        except Exception:
-            return "numpy"
+        return "jax"
     raise ValueError(f"unknown fleet backend {requested!r}: expected "
                      f"'numpy', 'jax' or 'auto'")
 
@@ -562,7 +559,8 @@ class FleetEngine:
     * ``dt_s`` (default 0.25) — fluid micro-step width, seconds.
     * ``backend`` — "numpy" | "jax" | "auto" (default "auto").
     * ``use_kernel`` — route the jax aging settle through the Pallas
-      kernel (default: only on TPU; the jnp oracle elsewhere).
+      kernel (default: only on TPU, compiled; the jnp oracle elsewhere.
+      ``True`` off-TPU runs the kernel in interpret mode).
     * ``checkpoint_dir`` — directory for periodic fleet checkpoints
       (written through ``repro.checkpoint.store``).
     * ``checkpoint_every_s`` (default 600) — simulated seconds between
@@ -585,7 +583,16 @@ class FleetEngine:
         self.checkpoint_every_s = float(opts.get("checkpoint_every_s",
                                                  600.0))
         self.resume = bool(opts.get("resume", True))
-        self._use_kernel = opts.get("use_kernel")
+        # The jax backend's aging-settle path, resolved here only and
+        # reported in the telemetry "engine" event.
+        on_tpu = False
+        if self.backend == "jax":
+            import jax
+            on_tpu = jax.default_backend() == "tpu"
+        self.use_kernel = (self.backend == "jax"
+                           and bool(opts.get("use_kernel", on_tpu)))
+        self.interpret = self.use_kernel and not on_tpu
+        self.compile_s = 0.0        # jax: wall seconds spent compiling
 
         dt = float(opts.get("dt_s", 0.25))
         if dt <= 0:
@@ -895,15 +902,17 @@ class FleetEngine:
         return (macro // per + 1) * per
 
     # -- jax driver ----------------------------------------------------- #
-    def _run_jax(self, arr: np.ndarray, start_macro: int) -> None:
+    def _build_jax(self, arr: np.ndarray):
+        """The jitted macro scan and its inputs: ``(scan, state, xs)``.
+        ``scan(state, xs[k:k2])`` advances macro steps ``k..k2``; ``xs``
+        holds every macro step's inputs along its leading axis."""
         import jax
         import jax.numpy as jnp
         from repro.kernels.aging_update.ops import advance_fleet
 
         sh = self.shape
         params = self.params
-        use_kernel = (self._use_kernel if self._use_kernel is not None
-                      else jax.default_backend() == "tpu")
+        use_kernel, interpret = self.use_kernel, self.interpret
         f0 = jnp.asarray(self.f0, jnp.float32)
         spp = sh.steps_per_period
         carbon = self._carbon_gate[1] if self._carbon_gate else None
@@ -930,7 +939,7 @@ class FleetEngine:
             tau_f = jnp.broadcast_to(tau, dvth.shape).reshape(-1)
             temp = jnp.full_like(flat, temp_c)
             out = advance_fleet(flat, temp, stress, tau_f, params,
-                                use_kernel=use_kernel)
+                                use_kernel=use_kernel, interpret=interpret)
             return out.reshape(dvth.shape)
 
         def micro_body(carry, xs):
@@ -1070,23 +1079,33 @@ class FleetEngine:
 
         n_steps = sh.n_macro * spp
         ts = (np.arange(n_steps) * sh.dt_s).astype(np.float32)
-        arr_m = jnp.asarray(arr.reshape(sh.n_macro, spp, 3), jnp.float32)
-        ts_m = jnp.asarray(ts.reshape(sh.n_macro, spp))
-        g_m = jnp.asarray(g_arr)
-
+        xs = (jnp.asarray(arr.reshape(sh.n_macro, spp, 3), jnp.float32),
+              jnp.asarray(ts.reshape(sh.n_macro, spp)),
+              jnp.asarray(g_arr))
+        if fx is not None:
+            xs = xs + (jnp.asarray(fx.up_frac, jnp.float32),
+                       jnp.asarray(fx.onset),
+                       jnp.asarray(fx.cap_mult, jnp.float32))
         scan = jax.jit(lambda st0, xs: jax.lax.scan(macro_body, st0, xs))
+        return scan, jst, xs
+
+    def _run_jax(self, arr: np.ndarray, start_macro: int) -> None:
+        sh = self.shape
+        scan, jst, xs = self._build_jax(arr)
         per = max(1, int(round(self.checkpoint_every_s
                                / self.cfg.idling_period_s)))
+        compiled = {}       # chunk length -> executable, compiled ahead
         k = start_macro
         while k < sh.n_macro:
             k2 = min(k + per, sh.n_macro) if self.checkpoint_dir \
                 else sh.n_macro
-            xs = (arr_m[k:k2], ts_m[k:k2], g_m[k:k2])
-            if fx is not None:
-                xs = xs + (jnp.asarray(fx.up_frac[k:k2], jnp.float32),
-                           jnp.asarray(fx.onset[k:k2]),
-                           jnp.asarray(fx.cap_mult[k:k2], jnp.float32))
-            jst, _ = scan(jst, xs)
+            chunk = tuple(x[k:k2] for x in xs)
+            step = compiled.get(k2 - k)
+            if step is None:
+                t0 = time.perf_counter()
+                step = compiled[k2 - k] = scan.lower(jst, chunk).compile()
+                self.compile_s += time.perf_counter() - t0
+            jst, _ = step(jst, chunk)
             k = k2
             self._unpack_jax(jst, k)
             if self.checkpoint_dir and k < sh.n_macro:
@@ -1292,8 +1311,14 @@ def run_fleet_experiment(cfg: ExperimentConfig, *, telemetry=None,
     engine = FleetEngine(cfg, telemetry=telemetry)
     if telemetry is not None:
         telemetry.event("engine", 0.0, engine="fleet",
-                        backend=engine.backend)
+                        backend=engine.backend,
+                        use_kernel=engine.use_kernel,
+                        interpret=engine.interpret)
     engine.run(requests)
+    if telemetry is not None and engine.backend == "jax":
+        telemetry.set_gauge("phase/jax_compile_wall_s", engine.compile_s)
+        telemetry.event("phase", 0.0, phase="jax_compile",
+                        wall_s=engine.compile_s)
     return engine.collect(carbon_model=carbon_model,
                           power_model=power_model, telemetry=telemetry)
 

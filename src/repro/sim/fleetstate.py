@@ -14,11 +14,12 @@ Backends:
            tests/test_fleetstate.py), so the serial simulation stays
            golden-exact.
   jax    — routes the stacked batch through the fleet-scale Pallas
-           kernel (`repro.kernels.aging_update`, float32; interpret
-           mode off-TPU). NOT bit-exact with the float64 numpy path —
-           for analytics sweeps and kernel-backed scale runs, not for
-           golden-pinned experiments.
-  auto   — jax when importable, numpy otherwise.
+           kernel (`repro.kernels.aging_update`, float32; compiled on
+           TPU, interpret mode elsewhere, resolved once in the
+           constructor as `interpret`). NOT bit-exact with the float64
+           numpy path — for analytics sweeps and kernel-backed scale
+           runs, not for golden-pinned experiments.
+  auto   — the jax backend.
 
 Managers must be homogeneous (same `AgingParams`, same core count) —
 exactly what a `Cluster` builds.
@@ -30,14 +31,6 @@ import numpy as np
 from repro.core import aging, temperature
 
 _BACKENDS = ("numpy", "jax", "auto")
-
-
-def _jax_available() -> bool:
-    try:
-        import jax  # noqa: F401
-    except Exception:
-        return False
-    return True
 
 
 class FleetAgingSettler:
@@ -61,9 +54,11 @@ class FleetAgingSettler:
         self.managers = managers
         self.params = params
         self.num_cores = n
-        if backend == "auto":
-            backend = "jax" if _jax_available() else "numpy"
-        self.backend = backend
+        self.backend = "jax" if backend == "auto" else backend
+        self.interpret = False
+        if self.backend == "jax":
+            import jax
+            self.interpret = jax.default_backend() != "tpu"
 
     # ------------------------------------------------------------------ #
     def _gather(self, now: float):
@@ -110,7 +105,8 @@ class FleetAgingSettler:
 
         shape = dvth.shape
         out = advance_fleet(dvth.ravel(), temps.ravel(), stress.ravel(),
-                            tau.ravel(), self.params)
+                            tau.ravel(), self.params,
+                            interpret=self.interpret)
         return np.asarray(out, dtype=np.float64).reshape(shape)
 
 

@@ -116,10 +116,10 @@ def _run_with_telemetry(cfg, hub, carbon_model, power_model,
     trace = phase("trace_gen", lambda: scenario.generate(
         rate_rps=cfg.rate_rps, duration_s=cfg.duration_s, seed=cfg.seed))
     cluster = phase("cluster_build", lambda: Cluster(cfg, telemetry=hub))
-    # Surface the aging settler's *resolved* backend ("auto" may have
-    # silently fallen back to numpy): visible in the event stream and as
-    # a gauge in `result.telemetry_summary`. The jax backend settles in
-    # float32 — fast, but not bit-exact vs the numpy reference.
+    # Surface the aging settler's *resolved* backend: visible in the
+    # event stream and as a gauge in `result.telemetry_summary`. The jax
+    # backend settles in float32 — fast, but not bit-exact vs the numpy
+    # reference.
     backend = cluster.fleet_settler.backend
     hub.event("engine", 0.0, engine="event", aging_backend=backend)
     hub.set_gauge("engine/aging_backend_is_jax",
@@ -175,8 +175,11 @@ def run_policy_sweep(
     `cfg.policy` / `cfg.scenario` / `cfg.router` / `cfg.power_model` /
     `cfg.fault_model` / `cfg.fleet`.
 
-    `parallel=N` fans the grid's cells across a process pool of N
-    workers. Every cell is an independent simulation whose seeding is
+    `parallel=N` fans the grid's host-only cells (event engine, numpy
+    fleet backend) across a process pool of N workers. Cells on the jax
+    fleet backend run in the calling process: a device belongs to one
+    process, and the caller that imported JAX already holds it. Every
+    cell is an independent simulation whose seeding is
     carried entirely by its frozen `ExperimentConfig` (each worker
     re-derives all RNG streams from `cell_cfg.seed`), so the result
     dict is identical to the serial sweep — same keys, same metrics —
@@ -232,17 +235,26 @@ def run_policy_sweep(
                                    + ((fl_name,) if fleet_axis else ()))
                             cells.append((key if len(key) > 1 else key[0],
                                           run_cfg))
-    if parallel is not None and int(parallel) > 1 and len(cells) > 1:
+    host = [c for _, c in cells if not _uses_jax(c)]
+    if parallel is not None and int(parallel) > 1 and len(host) > 1:
         import concurrent.futures
 
         with concurrent.futures.ProcessPoolExecutor(
                 max_workers=int(parallel)) as pool:
-            # `map` preserves submission order, so keys zip back exactly.
-            results = list(pool.map(run_experiment,
-                                    [c for _, c in cells]))
+            # `map` preserves submission order, so results zip back.
+            done = iter(pool.map(run_experiment, host))
+            results = [run_experiment(c) if _uses_jax(c) else next(done)
+                       for _, c in cells]
         return SweepResult(zip([k for k, _ in cells], results), axes=axes)
     return SweepResult(((key, run_experiment(run_cfg))
                         for key, run_cfg in cells), axes=axes)
+
+
+def _uses_jax(cfg: ExperimentConfig) -> bool:
+    """True for a cell that runs on the jax fleet backend (the device)."""
+    from repro.sim.fleetsim import _resolve_backend
+    return cfg.engine == "fleet" and _resolve_backend(
+        str(cfg.engine_options.get("backend", "auto"))) == "jax"
 
 
 def _with_policy(cfg: ExperimentConfig, policy) -> ExperimentConfig:

@@ -20,14 +20,6 @@ from repro.sim.fleetsim import FleetEngine, _resolve_backend
 from repro.sim.runner import run_experiment
 
 
-def _has_jax() -> bool:
-    try:
-        import jax  # noqa: F401
-        return True
-    except ImportError:
-        return False
-
-
 @pytest.fixture(scope="module")
 def event_result():
     return run_experiment(ExperimentConfig())
@@ -125,7 +117,6 @@ class TestBurstyScenarioParity:
         assert _rel(fl.fleet_energy_kwh, ev.fleet_energy_kwh) < 0.02
 
 
-@pytest.mark.skipif(not _has_jax(), reason="jax not installed")
 class TestBackendAgreement:
     """numpy (f64 reference) vs jax (f32 lax.scan) run the same
     functional step; agreement is close but not bit-exact."""
@@ -212,10 +203,54 @@ class TestConfigPlumbing:
 
     def test_backend_resolution(self):
         assert _resolve_backend("numpy") == "numpy"
-        expect = "jax" if _has_jax() else "numpy"
-        assert _resolve_backend("auto") == expect
+        assert _resolve_backend("jax") == "jax"
+        assert _resolve_backend("auto") == "jax"   # no numpy fallback
         with pytest.raises(ValueError, match="unknown fleet backend"):
             _resolve_backend("fortran")
+
+    @pytest.mark.parametrize("platform,opts,expect", [
+        ("cpu", {"backend": "numpy"}, (False, False)),
+        ("cpu", {"backend": "jax"}, (False, False)),     # jnp oracle
+        ("cpu", {"backend": "jax", "use_kernel": True}, (True, True)),
+        ("tpu", {"backend": "jax"}, (True, False)),      # compiled kernel
+        ("tpu", {"backend": "jax", "use_kernel": False}, (False, False)),
+    ])
+    def test_kernel_resolution(self, monkeypatch, platform, opts, expect):
+        """`use_kernel`/`interpret` are resolved once, from the platform
+        and the options; TPU never interprets the kernel."""
+        import jax
+        monkeypatch.setattr(jax, "default_backend", lambda: platform)
+        eng = FleetEngine(ExperimentConfig(duration_s=8.0).with_engine(
+            "fleet", **opts))
+        assert (eng.use_kernel, eng.interpret) == expect
+
+    def test_engine_event_reports_kernel_path(self):
+        from repro.telemetry import TelemetryHub
+        hub = TelemetryHub()
+        run_experiment(ExperimentConfig(duration_s=8.0).with_engine(
+            "fleet", backend="jax", use_kernel=True), telemetry=hub)
+        ev = next(e for e in hub.events if e["kind"] == "engine")
+        assert (ev["backend"], ev["use_kernel"], ev["interpret"]) == \
+            ("jax", True, True)
+        assert hub.gauge("phase/jax_compile_wall_s").value > 0.0
+
+    def test_parallel_sweep_keeps_jax_cells_in_process(self, monkeypatch):
+        """A device belongs to one process: jax fleet cells never go to
+        pool workers, whatever `parallel` asks for."""
+        import concurrent.futures
+
+        from repro.sim import run_policy_sweep
+
+        def no_pool(*a, **kw):
+            raise AssertionError("a jax cell was sent to a worker pool")
+        monkeypatch.setattr(concurrent.futures, "ProcessPoolExecutor",
+                            no_pool)
+        cfg = ExperimentConfig(duration_s=8.0).with_engine(
+            "fleet", backend="jax")
+        grid = run_policy_sweep(cfg, policies=("linux", "proposed"),
+                                parallel=2)
+        assert sorted(grid) == ["linux", "proposed"]
+        assert all(m.engine == "fleet" for m in grid.values())
 
 
 class TestScaleSmoke:

@@ -43,14 +43,6 @@ from repro.sim.routing import GenerationAwareRouter, get_router
 from repro.sim.runner import run_experiment
 
 
-def _has_jax() -> bool:
-    try:
-        import jax  # noqa: F401
-        return True
-    except ImportError:
-        return False
-
-
 # ---------------------------------------------------------------------- #
 # SKU catalog + registry axis
 # ---------------------------------------------------------------------- #
@@ -314,7 +306,6 @@ class TestRaggedFleetEngine:
         assert fl.fleet_yearly_total_kgco2eq == pytest.approx(
             ev.fleet_yearly_total_kgco2eq, rel=5e-3)
 
-    @pytest.mark.skipif(not _has_jax(), reason="jax not installed")
     def test_numpy_vs_jax_backend_parity(self):
         r_np = run_experiment(self.CFG.with_engine("fleet",
                                                    backend="numpy"))

@@ -126,7 +126,8 @@ class TestLengthShardedDecode:
         import jax
         from repro.models.attention import (decode_attention,
                                             decode_attention_length_sharded)
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = jax.make_mesh((1, 1), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         ks = jax.random.split(jax.random.key(0), 3)
         b, s, h, hkv, d = 2, 64, 8, 2, 16
         q = jax.random.normal(ks[0], (b, 1, h, d))
@@ -145,7 +146,8 @@ class TestLengthShardedDecode:
         import jax
         from repro.models.attention import (decode_attention,
                                             decode_attention_length_sharded)
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = jax.make_mesh((1, 1), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         ks = jax.random.split(jax.random.key(1), 3)
         b, s, h, d = 1, 48, 4, 8
         q = jax.random.normal(ks[0], (b, 1, h, d))

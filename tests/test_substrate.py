@@ -226,3 +226,28 @@ class TestEngine:
             # first token comes from prefill (recorded at admit), so the
             # stepped tokens are w[1:]
             assert outputs[rid] == w[1:], (rid, outputs[rid], w)
+
+
+class TestServeFit:
+    """`launch.serve` refuses, with the numbers, a model whose weights
+    and KV cache exceed the device, instead of dying out of memory."""
+
+    class _Chip:
+        device_kind = "TPU v5 lite"
+
+        def memory_stats(self):
+            return {"bytes_limit": 16 * 10**9}
+
+    @pytest.mark.parametrize("arch,fits", [("minicpm3-4b", True),
+                                           ("llama3-8b", False)])
+    def test_full_configs_on_one_v5e(self, monkeypatch, arch, fits):
+        from repro.configs import get_config
+        from repro.launch import serve
+        from repro.models import Model
+        monkeypatch.setattr(jax, "devices", lambda: [self._Chip()])
+        model = Model(get_config(arch))
+        if fits:
+            serve.check_fits(model, max_batch=8, max_len=2048)
+        else:
+            with pytest.raises(SystemExit, match="needs .* GiB"):
+                serve.check_fits(model, max_batch=8, max_len=2048)
