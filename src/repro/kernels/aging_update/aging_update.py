@@ -54,4 +54,5 @@ def aging_update(dvth, temp_c, stress, tau, params, interpret=False):
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((n_cores,), jnp.float32),
         interpret=interpret,
+        name="aging_update",
     )(dvth, temp_c, stress, tau)

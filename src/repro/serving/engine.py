@@ -9,6 +9,13 @@ scheduling, and routes every host-side operation through a `CoreManager`
 completion -> `finish_request`/`free_memory`. The manager's Selective
 Core Idling runs on a wall-clock period, so an idle engine deep-idles its
 host cores (age-halting) and a bursty one wakes them.
+
+Each step is the program span `engine.step`, with `engine.admit` (per
+admitted request: its id and its wait in the queue), `engine.prefill`
+and `engine.splice` inside it, then `engine.decode` (active and total
+slots), `engine.tokens` (the per-slot token reads and retirements) and
+`engine.core_manager` (each call into the core manager); see
+`repro.telemetry.spans`.
 """
 from __future__ import annotations
 
@@ -23,6 +30,7 @@ import numpy as np
 from repro.core import CoreManager, CorePolicy
 from repro.models import Model
 from repro.sim.tasks import TaskIdAllocator
+from repro.telemetry.spans import span
 
 
 @dataclasses.dataclass
@@ -32,6 +40,7 @@ class Request:
     max_new_tokens: int
     output: list[int] = dataclasses.field(default_factory=list)
     done: bool = False
+    submit_s: float = 0.0       # engine clock at `submit`
 
 
 class InferenceEngine:
@@ -62,6 +71,10 @@ class InferenceEngine:
         # hub type, the same probes, exported via `prometheus_text()` —
         # first step toward running the simulator as a digital twin.
         self.telemetry = telemetry
+        self._queue_wait = self._active_slots = None
+        if telemetry is not None:
+            self._queue_wait = telemetry.get_series("engine/queue_wait_s")
+            self._active_slots = telemetry.get_series("engine/active_slots")
         self.core_manager = CoreManager(num_host_cores, policy=policy,
                                         rng=np.random.default_rng(0),
                                         telemetry=telemetry)
@@ -107,20 +120,24 @@ class InferenceEngine:
 
     def _cpu_task(self, name: str) -> None:
         """Account one Table-2 host task against the core manager."""
-        task = self._task_ids.new(name)
-        t = self._now()
-        self.core_manager.assign(task.task_id, t)
-        self.core_manager.release(task.task_id, t + task.duration_s)
+        with span("engine.core_manager"):
+            task = self._task_ids.new(name)
+            t = self._now()
+            self.core_manager.assign(task.task_id, t)
+            self.core_manager.release(task.task_id, t + task.duration_s)
 
     def _periodic(self) -> None:
-        t = self._now()
-        if t - self._last_idle_check >= self.core_manager.idling_period_s:
-            self.core_manager.periodic(t)
-            self._last_idle_check = t
+        with span("engine.core_manager"):
+            t = self._now()
+            if t - self._last_idle_check >= \
+                    self.core_manager.idling_period_s:
+                self.core_manager.periodic(t)
+                self._last_idle_check = t
 
     def submit(self, prompt: list[int], max_new_tokens: int = 32) -> int:
         self._cpu_task("submit")
-        req = Request(self._next_id, list(prompt), max_new_tokens)
+        req = Request(self._next_id, list(prompt), max_new_tokens,
+                      submit_s=self._now())
         self._next_id += 1
         self.pending.append(req)
         return req.req_id
@@ -130,27 +147,41 @@ class InferenceEngine:
             if self.slots[i] is not None or not self.pending:
                 continue
             req = self.pending.pop(0)
-            self._cpu_task("alloc_memory")
+            now = self._now()
+            wait = now - req.submit_s
+            if self._queue_wait is not None:
+                self._queue_wait.observe(now, wait)
+            with span("engine.admit", req_id=req.req_id,
+                      wait_ms=wait * 1e3):
+                self._admit_one(i, req)
+
+    def _admit_one(self, i: int, req: Request) -> None:
+        """Prefill `req` into slot `i` and read its first token."""
+        self._cpu_task("alloc_memory")
+        with span("engine.prefill"):
             toks = jnp.asarray(req.prompt, jnp.int32)[None, :]
             logits, pcache = self._prefill(self.params, toks, self.max_len)
-            # splice the single-row prefill cache into slot i
-            def splice(big, small):
-                if small.ndim == 0:
-                    return big
-                return big.at[:, i].set(small[:, 0])
+
+        # splice the single-row prefill cache into slot i
+        def splice(big, small):
+            if small.ndim == 0:
+                return big
+            return big.at[:, i].set(small[:, 0])
+        with span("engine.splice"):
             new_cache = {}
             for key in self.cache:
                 if key == "pos":
-                    new_cache[key] = self.cache[key].at[i].set(len(req.prompt))
+                    new_cache[key] = self.cache[key].at[i].set(
+                        len(req.prompt))
                 else:
                     new_cache[key] = jax.tree.map(
                         splice, self.cache[key], pcache[key])
             self.cache = new_cache
-            first = self._select_token(logits[:, -1])
-            req.output.append(int(first[0]))
-            self.tokens = self.tokens.at[i, 0].set(first[0])
-            self.slots[i] = req
-            self.active_mask[i] = True
+        first = self._select_token(logits[:, -1])
+        req.output.append(int(first[0]))
+        self.tokens = self.tokens.at[i, 0].set(first[0])
+        self.slots[i] = req
+        self.active_mask[i] = True
 
     def _select_token(self, logits_row: jax.Array) -> jax.Array:
         v = self.model.cfg.vocab_size
@@ -164,30 +195,39 @@ class InferenceEngine:
     def step(self) -> list[tuple[int, int]]:
         """One engine iteration: admit pending, batched decode, retire
         finished. Returns [(req_id, new_token), ...]."""
+        with span("engine.step"):
+            return self._step()
+
+    def _step(self) -> list[tuple[int, int]]:
         self._periodic()
         self._admit()
-        if not self.active_mask.any():
+        n_active = int(self.active_mask.sum())
+        if not n_active:
             return []
         self._cpu_task("start_iteration")
-        active = jnp.asarray(self.active_mask)
-        logits, self.cache = self._decode(self.params, self.cache,
-                                          self.tokens, active)
-        new_tokens = self._select_token(logits[:, 0])
+        if self._active_slots is not None:
+            self._active_slots.observe(self._now(), n_active)
+        with span("engine.decode", active=n_active, slots=self.max_batch):
+            active = jnp.asarray(self.active_mask)
+            logits, self.cache = self._decode(self.params, self.cache,
+                                              self.tokens, active)
+            new_tokens = self._select_token(logits[:, 0])
         out = []
-        for i, req in enumerate(self.slots):
-            if req is None:
-                continue
-            tok = int(new_tokens[i])
-            req.output.append(tok)
-            out.append((req.req_id, tok))
-            self.tokens = self.tokens.at[i, 0].set(tok)
-            hit_eos = self.eos_id is not None and tok == self.eos_id
-            if len(req.output) >= req.max_new_tokens or hit_eos:
-                req.done = True
-                self._cpu_task("finish_request")
-                self._cpu_task("free_memory")
-                self.slots[i] = None
-                self.active_mask[i] = False
+        with span("engine.tokens"):
+            for i, req in enumerate(self.slots):
+                if req is None:
+                    continue
+                tok = int(new_tokens[i])
+                req.output.append(tok)
+                out.append((req.req_id, tok))
+                self.tokens = self.tokens.at[i, 0].set(tok)
+                hit_eos = self.eos_id is not None and tok == self.eos_id
+                if len(req.output) >= req.max_new_tokens or hit_eos:
+                    req.done = True
+                    self._cpu_task("finish_request")
+                    self._cpu_task("free_memory")
+                    self.slots[i] = None
+                    self.active_mask[i] = False
         return out
 
     def run_until_drained(self, max_steps: int = 10_000) -> None:

@@ -62,7 +62,6 @@ the event engine on small configs is pinned with tolerances in
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Any
 
 import numpy as np
@@ -83,6 +82,7 @@ from repro.sim.cluster import (
 from repro.sim.config import ExperimentConfig
 from repro.sim.results import ExperimentResult
 from repro.sim.tasks import TASK_DURATIONS_S
+from repro.telemetry.spans import Phase, span
 
 # ---------------------------------------------------------------------- #
 # Per-request CPU work (nominal core-seconds), assembled from the same
@@ -592,7 +592,11 @@ class FleetEngine:
         self.use_kernel = (self.backend == "jax"
                            and bool(opts.get("use_kernel", on_tpu)))
         self.interpret = self.use_kernel and not on_tpu
-        self.compile_s = 0.0        # jax: wall seconds spent compiling
+        # jax: lowering and compiling the macro scan, the span
+        # `fleetsim.compile` and the gauge `phase/jax_compile_wall_s`
+        self._compile = Phase("fleetsim.compile",
+                              telemetry if self.backend == "jax" else None,
+                              gauge="jax_compile")
 
         dt = float(opts.get("dt_s", 0.25))
         if dt <= 0:
@@ -714,10 +718,11 @@ class FleetEngine:
     # ------------------------------------------------------------------ #
     def run(self, requests) -> None:
         sh = self.shape
-        sh.mean_out_tokens = (float(np.mean([r.output_tokens
-                                             for r in requests]))
-                              if requests else 1.0)
-        arr = self._bin_trace(requests)
+        with span("fleetsim.bin"):
+            sh.mean_out_tokens = (float(np.mean([r.output_tokens
+                                                 for r in requests]))
+                                  if requests else 1.0)
+            arr = self._bin_trace(requests)
         self._requests = requests
         start = 0
         if self.checkpoint_dir and self.resume:
@@ -1086,12 +1091,17 @@ class FleetEngine:
             xs = xs + (jnp.asarray(fx.up_frac, jnp.float32),
                        jnp.asarray(fx.onset),
                        jnp.asarray(fx.cap_mult, jnp.float32))
-        scan = jax.jit(lambda st0, xs: jax.lax.scan(macro_body, st0, xs))
-        return scan, jst, xs
+
+        @jax.jit
+        def fleet_scan(st0, xs):
+            return jax.lax.scan(macro_body, st0, xs)
+        return fleet_scan, jst, xs
 
     def _run_jax(self, arr: np.ndarray, start_macro: int) -> None:
+        import jax
         sh = self.shape
-        scan, jst, xs = self._build_jax(arr)
+        with span("fleetsim.build"):
+            scan, jst, xs = self._build_jax(arr)
         per = max(1, int(round(self.checkpoint_every_s
                                / self.cfg.idling_period_s)))
         compiled = {}       # chunk length -> executable, compiled ahead
@@ -1102,12 +1112,14 @@ class FleetEngine:
             chunk = tuple(x[k:k2] for x in xs)
             step = compiled.get(k2 - k)
             if step is None:
-                t0 = time.perf_counter()
-                step = compiled[k2 - k] = scan.lower(jst, chunk).compile()
-                self.compile_s += time.perf_counter() - t0
-            jst, _ = step(jst, chunk)
+                with self._compile:
+                    step = compiled[k2 - k] = scan.lower(jst, chunk).compile()
+            with span("fleetsim.scan"):
+                jst, _ = step(jst, chunk)
+                jax.block_until_ready(jst)
             k = k2
-            self._unpack_jax(jst, k)
+            with span("fleetsim.unpack"):
+                self._unpack_jax(jst, k)
             if self.checkpoint_dir and k < sh.n_macro:
                 self._checkpoint(k)
 
@@ -1228,6 +1240,11 @@ class FleetEngine:
 
     def collect(self, carbon_model=None, power_model=None,
                 telemetry=None) -> ExperimentResult:
+        with span("fleetsim.collect"):
+            return self._collect(carbon_model, power_model, telemetry)
+
+    def _collect(self, carbon_model, power_model,
+                 telemetry) -> ExperimentResult:
         sh, st = self.shape, self.state
         f = self.f0 * (1.0 - st["dvth"] / self.params.headroom)
         if self._pad is None:
@@ -1300,27 +1317,26 @@ def run_fleet_experiment(cfg: ExperimentConfig, *, telemetry=None,
                          requests=None) -> ExperimentResult:
     """Generate the trace, run the fleet engine, collect the result.
     Mirrors `run_experiment`'s event path; `requests` short-circuits
-    trace generation when the caller already has it."""
-    if scenario is None:
-        from repro.workloads import get_scenario
-        scenario = get_scenario(cfg.scenario, **cfg.scenario_options)
-    if requests is None:
-        requests = scenario.generate(rate_rps=cfg.rate_rps,
-                                     duration_s=cfg.duration_s,
-                                     seed=cfg.seed)
-    engine = FleetEngine(cfg, telemetry=telemetry)
-    if telemetry is not None:
-        telemetry.event("engine", 0.0, engine="fleet",
-                        backend=engine.backend,
-                        use_kernel=engine.use_kernel,
-                        interpret=engine.interpret)
-    engine.run(requests)
-    if telemetry is not None and engine.backend == "jax":
-        telemetry.set_gauge("phase/jax_compile_wall_s", engine.compile_s)
-        telemetry.event("phase", 0.0, phase="jax_compile",
-                        wall_s=engine.compile_s)
-    return engine.collect(carbon_model=carbon_model,
-                          power_model=power_model, telemetry=telemetry)
+    trace generation when the caller already has it. The whole call is
+    the span `fleetsim.experiment`, with the engine's phases inside."""
+    with span("fleetsim.experiment", seed=cfg.seed):
+        if scenario is None:
+            from repro.workloads import get_scenario
+            scenario = get_scenario(cfg.scenario, **cfg.scenario_options)
+        if requests is None:
+            requests = scenario.generate(rate_rps=cfg.rate_rps,
+                                         duration_s=cfg.duration_s,
+                                         seed=cfg.seed)
+        with span("fleetsim.build"):
+            engine = FleetEngine(cfg, telemetry=telemetry)
+        if telemetry is not None:
+            telemetry.event("engine", 0.0, engine="fleet",
+                            backend=engine.backend,
+                            use_kernel=engine.use_kernel,
+                            interpret=engine.interpret)
+        engine.run(requests)
+        return engine.collect(carbon_model=carbon_model,
+                              power_model=power_model, telemetry=telemetry)
 
 
 __all__: list[str] = ["FleetEngine", "run_fleet_experiment"]

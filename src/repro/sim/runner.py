@@ -98,20 +98,18 @@ def run_experiment(cfg: ExperimentConfig,
 def _run_with_telemetry(cfg, hub, carbon_model, power_model,
                         scenario) -> ExperimentResult:
     """Telemetry-on path: same simulation, plus per-phase wall-time /
-    event-loop-throughput self-profiling and post-run export. Recording
-    is pure observation, so the `ExperimentResult` scalars stay
-    bit-identical to the hub-less path (pinned in
-    tests/test_telemetry.py)."""
+    event-loop-throughput self-profiling and post-run export. Each phase
+    is a program span `sim.<phase>` that sets the gauge
+    `phase/<phase>_wall_s`. Recording is pure observation, so the
+    `ExperimentResult` scalars stay bit-identical to the hub-less path
+    (pinned in tests/test_telemetry.py)."""
     import dataclasses
-    import time
+
+    from repro.telemetry import Phase
 
     def phase(name, fn):
-        t0 = time.perf_counter()
-        out = fn()
-        dt = time.perf_counter() - t0
-        hub.set_gauge(f"phase/{name}_wall_s", dt)
-        hub.event("phase", 0.0, phase=name, wall_s=dt)
-        return out
+        with Phase(f"sim.{name}", hub, gauge=name):
+            return fn()
 
     trace = phase("trace_gen", lambda: scenario.generate(
         rate_rps=cfg.rate_rps, duration_s=cfg.duration_s, seed=cfg.seed))

@@ -1,8 +1,10 @@
 """Streaming telemetry: typed probes, structured event tracing, and
-exporters (JSONL / Chrome trace / CSV / npz / Prometheus text).
+exporters (JSONL / Chrome trace / CSV / npz / Prometheus text), plus
+program spans on the profiler's clock.
 
-See `repro.telemetry.hub` for the probe taxonomy and
-`repro.telemetry.export` for the export surfaces.
+See `repro.telemetry.hub` for the probe taxonomy,
+`repro.telemetry.export` for the export surfaces and
+`repro.telemetry.spans` for the spans.
 """
 from repro.telemetry.hub import (
     NULL_HUB,
@@ -27,6 +29,7 @@ from repro.telemetry.export import (
     write_chrome_trace,
     write_jsonl,
 )
+from repro.telemetry.spans import Phase, span
 
 __all__ = [
     "TelemetryHub", "NullHub", "NULL_HUB", "Counter", "Gauge",
@@ -34,4 +37,5 @@ __all__ = [
     "EVENT_SCHEMA_VERSION", "write_jsonl", "read_jsonl", "chrome_trace",
     "write_chrome_trace", "series_to_csv", "series_to_npz",
     "prometheus_text", "export_run", "start_metrics_server",
+    "Phase", "span",
 ]

@@ -110,8 +110,8 @@ def chrome_trace(events: Iterable[dict[str, Any]],
     into per-task *oversub* spans on a dedicated per-machine lane
     (`tid = oversub_tid`). Spans still open at the end of the stream
     are closed at `t_end` (default: the last event time). Point events
-    (`carbon_deferral`, `route`, `phase`) become instants so cause
-    records stay visible next to the spans they explain.
+    (`carbon_deferral`, `route`) become instants so cause records stay
+    visible next to the spans they explain.
     """
     events = list(events)
     if t_end is None:
@@ -165,7 +165,7 @@ def chrome_trace(events: Iterable[dict[str, Any]],
                                  {"gate_cause": opened[1],
                                   "wake_cause": e.get("cause",
                                                       "policy")}))
-        elif kind in ("carbon_deferral", "route", "phase"):
+        elif kind in ("carbon_deferral", "route"):
             args = {k: v for k, v in e.items()
                     if k not in ("kind", "t", "machine")}
             out.append({"name": kind, "cat": kind, "ph": "i", "s": "p",

@@ -17,8 +17,13 @@ publishes into:
   * structured event log    — ring-buffered dicts with cause
                               attribution (`gate` / `wake` / `assign` /
                               `promote` / `oversub` / `carbon_deferral`
-                              / `route` / `phase`), the raw stream the
-                              JSONL and Chrome-trace exporters replay.
+                              / `route` / `engine`), the raw stream
+                              the JSONL and Chrome-trace exporters
+                              replay, on the simulated clock.
+
+Wall-clock phases are not events: they are program spans on the
+profiler's clock (`repro.telemetry.spans`), whose `Phase` also sets
+the `phase/<name>_wall_s` gauges.
 
 Everything is bounded: events and timelines are `deque(maxlen=...)`
 rings, series retain the last `max_windows` windows; overflow counts
@@ -95,7 +100,11 @@ class Counter:
 
 
 class Gauge:
-    """Last-value probe (`events_per_sec`, phase wall times, ...)."""
+    """Last-value probe (`events_per_sec`, phase wall times, ...).
+
+    A phase's wall time (`phase/<name>_wall_s`) is set by
+    `repro.telemetry.spans.Phase`, from the same reading as the
+    phase's profiler span."""
 
     __slots__ = ("name", "value")
 

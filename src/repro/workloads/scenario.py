@@ -27,6 +27,7 @@ from typing import Callable
 
 import numpy as np
 
+from repro.telemetry.spans import span
 from repro.workloads import arrivals as arr
 from repro.workloads import mixes
 from repro.workloads.base import ArrivalProcess, Request, TokenMix
@@ -52,16 +53,18 @@ class Scenario:
         if rate_rps <= 0 or duration_s <= 0:
             raise ValueError("rate_rps and duration_s must be positive, "
                              f"got {rate_rps}/{duration_s}")
-        rng = np.random.default_rng(seed)
-        process = self.arrival_factory(rate_rps, duration_s)
-        requests: list[Request] = []
-        t = 0.0
-        while True:
-            t += process.next_gap(rng, t)
-            if t >= duration_s:
-                break
-            n_in, n_out = self.mix.sample_one(rng)
-            requests.append(Request(len(requests), t, n_in, n_out))
+        with span("workloads.generate") as sp:
+            rng = np.random.default_rng(seed)
+            process = self.arrival_factory(rate_rps, duration_s)
+            requests: list[Request] = []
+            t = 0.0
+            while True:
+                t += process.next_gap(rng, t)
+                if t >= duration_s:
+                    break
+                n_in, n_out = self.mix.sample_one(rng)
+                requests.append(Request(len(requests), t, n_in, n_out))
+            sp.set_metadata(requests=len(requests))
         return requests
 
 
