@@ -83,6 +83,7 @@ from repro.sim.config import ExperimentConfig
 from repro.sim.results import ExperimentResult
 from repro.sim.tasks import TASK_DURATIONS_S
 from repro.telemetry.spans import Phase, span
+from repro.workloads.base import RequestColumns
 
 # ---------------------------------------------------------------------- #
 # Per-request CPU work (nominal core-seconds), assembled from the same
@@ -116,6 +117,13 @@ _KV_S_PER_TOKEN = KV_BYTES_PER_TOKEN / IB_LINK_BW_BPS
 
 _IDLE_BINS = 512          # linear histogram over [-1, 1] for idle_norm
 _EPS = 1e-12
+
+
+def _float_columns(requests: RequestColumns):
+    """(arrival_s, input_tokens, output_tokens) of a trace, all float64."""
+    return (requests.arrival_s,
+            requests.input_tokens.astype(np.float64),
+            requests.output_tokens.astype(np.float64))
 
 
 def _resolve_backend(requested: str) -> str:
@@ -693,20 +701,15 @@ class FleetEngine:
     # ------------------------------------------------------------------ #
     # trace binning
     # ------------------------------------------------------------------ #
-    def _bin_trace(self, requests) -> np.ndarray:
+    def _bin_trace(self, requests: RequestColumns) -> np.ndarray:
         """(T_micro, 3) per-step [arrival count, input-token sum,
         output-token sum] from the scenario's request trace."""
         sh = self.shape
         n_steps = sh.n_macro * sh.steps_per_period
         out = np.zeros((n_steps, 3))
-        if not requests:
+        if not len(requests):
             return out
-        t_arr = np.fromiter((r.arrival_s for r in requests), float,
-                            count=len(requests))
-        n_in = np.fromiter((r.input_tokens for r in requests), float,
-                           count=len(requests))
-        n_out = np.fromiter((r.output_tokens for r in requests), float,
-                            count=len(requests))
+        t_arr, n_in, n_out = _float_columns(requests)
         idx = np.clip((t_arr / sh.dt_s).astype(np.int64), 0, n_steps - 1)
         out[:, 0] = np.bincount(idx, minlength=n_steps)
         out[:, 1] = np.bincount(idx, weights=n_in, minlength=n_steps)
@@ -717,11 +720,18 @@ class FleetEngine:
     # run
     # ------------------------------------------------------------------ #
     def run(self, requests) -> None:
+        """Run the trace: `RequestColumns`, or a `Request` sequence,
+        which is turned into columns once (the span `fleetsim.bin`
+        counts those requests as `converted`)."""
         sh = self.shape
-        with span("fleetsim.bin"):
-            sh.mean_out_tokens = (float(np.mean([r.output_tokens
-                                                 for r in requests]))
-                                  if requests else 1.0)
+        with span("fleetsim.bin") as sp:
+            converted = 0
+            if not isinstance(requests, RequestColumns):
+                requests = RequestColumns.from_requests(requests)
+                converted = len(requests)
+            sp.set_metadata(converted=converted)
+            sh.mean_out_tokens = (float(np.mean(requests.output_tokens))
+                                  if len(requests) else 1.0)
             arr = self._bin_trace(requests)
         self._requests = requests
         start = 0
@@ -1168,16 +1178,10 @@ class FleetEngine:
         a transient vectorized pass over the trace (no per-request state
         is held by the engine)."""
         sh = self.shape
-        requests = self._requests
-        if not requests:
+        if not len(self._requests):
             return float("nan"), float("nan"), 0
         mw = self._window_means()
-        t_arr = np.fromiter((r.arrival_s for r in requests), float,
-                            count=len(requests))
-        n_in = np.fromiter((r.input_tokens for r in requests), float,
-                           count=len(requests))
-        n_out = np.fromiter((r.output_tokens for r in requests), float,
-                            count=len(requests))
+        t_arr, n_in, n_out = _float_columns(self._requests)
         w = np.clip((t_arr / sh.mwin_s).astype(np.int64), 0,
                     sh.n_mwin - 1)
         sp = mw["mw_sp"][w]
@@ -1324,9 +1328,11 @@ def run_fleet_experiment(cfg: ExperimentConfig, *, telemetry=None,
             from repro.workloads import get_scenario
             scenario = get_scenario(cfg.scenario, **cfg.scenario_options)
         if requests is None:
-            requests = scenario.generate(rate_rps=cfg.rate_rps,
-                                         duration_s=cfg.duration_s,
-                                         seed=cfg.seed)
+            # columns where the scenario draws them; a scenario with only
+            # `generate` gives a list, which `run` converts once
+            draw = getattr(scenario, "generate_columns", scenario.generate)
+            requests = draw(rate_rps=cfg.rate_rps,
+                            duration_s=cfg.duration_s, seed=cfg.seed)
         with span("fleetsim.build"):
             engine = FleetEngine(cfg, telemetry=telemetry)
         if telemetry is not None:

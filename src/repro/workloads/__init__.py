@@ -18,6 +18,10 @@ and a string-keyed registry of named scenarios:
     trace = get_scenario("conversation-mmpp").generate(
         rate_rps=60, duration_s=120, seed=0)
 
+`Scenario.generate_columns` draws the same trace as `RequestColumns`
+(three numpy arrays), which the fleet engine reads without a `Request`
+object per request.
+
 Experiments select scenarios by name: `ExperimentConfig(scenario=...)`,
 and `run_policy_sweep(..., scenarios=(...))` runs policy x scenario
 grids. Adding a scenario:
@@ -29,8 +33,8 @@ grids. Adding a scenario:
         return Scenario("my-scenario", mixes.CONVERSATION, my_arrivals)
 """
 from repro.workloads import arrivals, mixes, traceio
-from repro.workloads.base import (ArrivalProcess, Request, TokenMix,
-                                  WorkloadScenario, request_stats)
+from repro.workloads.base import (ArrivalProcess, Request, RequestColumns,
+                                  TokenMix, WorkloadScenario, request_stats)
 from repro.workloads.registry import (available_scenarios,
                                       canonical_scenario_name, get_scenario,
                                       register_scenario)
@@ -41,9 +45,10 @@ from repro.workloads.traceio import (ReplayScenario, export_csv,
                                      splice, time_scale)
 
 __all__ = [
-    "ArrivalProcess", "Request", "TokenMix", "WorkloadScenario",
-    "request_stats", "available_scenarios", "canonical_scenario_name",
-    "get_scenario", "register_scenario", "Scenario", "ReplayScenario",
-    "export_csv", "export_csv_str", "load_csv", "rescale_rate", "splice",
-    "time_scale", "arrivals", "mixes", "traceio",
+    "ArrivalProcess", "Request", "RequestColumns", "TokenMix",
+    "WorkloadScenario", "request_stats", "available_scenarios",
+    "canonical_scenario_name", "get_scenario", "register_scenario",
+    "Scenario", "ReplayScenario", "export_csv", "export_csv_str",
+    "load_csv", "rescale_rate", "splice", "time_scale", "arrivals",
+    "mixes", "traceio",
 ]

@@ -5,8 +5,9 @@ are).
 
 The paper's evaluation (§6.1.2) replays Azure LLM inference traces, which
 characterize each request by (arrival time, input tokens, output tokens).
-`Request` is exactly that triple plus an id. A scenario is anything that
-can turn (rate, duration, seed) into a deterministic `Request` list; the
+`Request` is exactly that triple plus an id, and `RequestColumns` a
+whole trace of them as three arrays. A scenario is anything that can
+turn (rate, duration, seed) into a deterministic `Request` list; the
 built-in `Scenario` composition interleaves one arrival-gap draw with one
 token-mix draw per request from a single seeded generator, so scenarios
 are reproducible bit-for-bit across runs and platforms.
@@ -27,6 +28,54 @@ class Request:
     arrival_s: float
     input_tokens: int
     output_tokens: int
+
+
+def _frozen(values, dtype) -> np.ndarray:
+    out = np.array(values, dtype=dtype)
+    out.flags.writeable = False
+    return out
+
+
+@dataclasses.dataclass(frozen=True, eq=False)
+class RequestColumns:
+    """A request trace as three read-only columns: `arrival_s`
+    (float64), `input_tokens` and `output_tokens` (int64). Request `i`
+    has id `i`. The fleet engine reads these arrays directly;
+    `to_requests()` gives the equal `Request` list for consumers that
+    walk objects."""
+
+    arrival_s: np.ndarray
+    input_tokens: np.ndarray
+    output_tokens: np.ndarray
+
+    def __post_init__(self):
+        cols = (_frozen(self.arrival_s, np.float64),
+                _frozen(self.input_tokens, np.int64),
+                _frozen(self.output_tokens, np.int64))
+        if any(c.shape != (len(cols[0]),) for c in cols):
+            raise ValueError("request columns must be 1-D and of one "
+                             f"length, got {[c.shape for c in cols]}")
+        for name, col in zip(("arrival_s", "input_tokens",
+                              "output_tokens"), cols):
+            object.__setattr__(self, name, col)
+
+    def __len__(self) -> int:
+        return len(self.arrival_s)
+
+    def to_requests(self) -> list[Request]:
+        return list(map(Request, range(len(self)), self.arrival_s.tolist(),
+                        self.input_tokens.tolist(),
+                        self.output_tokens.tolist()))
+
+    @classmethod
+    def from_requests(cls, requests) -> "RequestColumns":
+        """Columns of a `Request` sequence, in its order (ids are not
+        kept: request `i` of the columns is the sequence's `i`-th)."""
+        n = len(requests)
+        return cls(
+            np.fromiter((r.arrival_s for r in requests), np.float64, n),
+            np.fromiter((r.input_tokens for r in requests), np.int64, n),
+            np.fromiter((r.output_tokens for r in requests), np.int64, n))
 
 
 @runtime_checkable

@@ -1,13 +1,14 @@
 """`Scenario` — composition of an arrival process and a token mix —
 plus the built-in scenario library.
 
-A `Scenario` turns (rate_rps, duration_s, seed) into a `Request` list by
+A `Scenario` turns (rate_rps, duration_s, seed) into a request trace by
 interleaving one arrival-gap draw with one token-mix draw per request
-from a single `np.random.default_rng(seed)`. For the homogeneous-Poisson
-conversation scenario this reproduces the pre-subsystem
-`sim.trace.generate` draw sequence exactly, so `conversation-poisson`
-is bit-identical to the legacy generator (golden-pinned in
-tests/test_workloads.py).
+from a single `np.random.default_rng(seed)`: `generate_columns` gives
+it as `RequestColumns`, `generate` as the equal `Request` list. For the
+homogeneous-Poisson conversation scenario this reproduces the
+pre-subsystem `sim.trace.generate` draw sequence exactly, so
+`conversation-poisson` is bit-identical to the legacy generator
+(golden-pinned in tests/test_workloads.py).
 
 Built-ins registered here (see `available_scenarios()`):
 
@@ -30,7 +31,8 @@ import numpy as np
 from repro.telemetry.spans import span
 from repro.workloads import arrivals as arr
 from repro.workloads import mixes
-from repro.workloads.base import ArrivalProcess, Request, TokenMix
+from repro.workloads.base import (ArrivalProcess, Request, RequestColumns,
+                                  TokenMix)
 from repro.workloads.registry import register_scenario
 
 
@@ -48,24 +50,38 @@ class Scenario:
     arrival_factory: Callable[[float, float], ArrivalProcess]
     description: str = ""
 
-    def generate(self, rate_rps: float = 60.0, duration_s: float = 120.0,
-                 seed: int = 0) -> list[Request]:
+    def generate_columns(self, rate_rps: float = 60.0,
+                         duration_s: float = 120.0,
+                         seed: int = 0) -> RequestColumns:
+        """The trace as columns: the draws of `generate`, in its order,
+        without a `Request` object per request."""
         if rate_rps <= 0 or duration_s <= 0:
             raise ValueError("rate_rps and duration_s must be positive, "
                              f"got {rate_rps}/{duration_s}")
         with span("workloads.generate") as sp:
             rng = np.random.default_rng(seed)
-            process = self.arrival_factory(rate_rps, duration_s)
-            requests: list[Request] = []
+            next_gap = self.arrival_factory(rate_rps, duration_s).next_gap
+            sample_one = self.mix.sample_one
+            arrival_s: list[float] = []
+            input_tokens: list[int] = []
+            output_tokens: list[int] = []
+            add_t, add_in = arrival_s.append, input_tokens.append
+            add_out = output_tokens.append
             t = 0.0
             while True:
-                t += process.next_gap(rng, t)
+                t += next_gap(rng, t)
                 if t >= duration_s:
                     break
-                n_in, n_out = self.mix.sample_one(rng)
-                requests.append(Request(len(requests), t, n_in, n_out))
-            sp.set_metadata(requests=len(requests))
-        return requests
+                n_in, n_out = sample_one(rng)
+                add_t(t)
+                add_in(n_in)
+                add_out(n_out)
+            sp.set_metadata(requests=len(arrival_s))
+        return RequestColumns(arrival_s, input_tokens, output_tokens)
+
+    def generate(self, rate_rps: float = 60.0, duration_s: float = 120.0,
+                 seed: int = 0) -> list[Request]:
+        return self.generate_columns(rate_rps, duration_s, seed).to_requests()
 
 
 # --------------------------- built-ins -------------------------------- #

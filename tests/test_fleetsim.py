@@ -253,6 +253,50 @@ class TestConfigPlumbing:
         assert all(m.engine == "fleet" for m in grid.values())
 
 
+class TestTraceColumns:
+    """The engine reads the trace as columns; a `Request` list is turned
+    into the same columns, so both give the same bits."""
+
+    CFG = ExperimentConfig(duration_s=60.0).with_engine("fleet",
+                                                        backend="numpy")
+
+    @staticmethod
+    def _same(a, b, ignore=()):
+        a, b = a.scalars(), b.scalars()
+        assert a.keys() == b.keys()
+        for k in a.keys() - set(ignore):
+            assert a[k] == b[k] or (a[k] != a[k] and b[k] != b[k]), k
+
+    def test_columns_and_request_list_agree(self):
+        from repro.sim.fleetsim import run_fleet_experiment
+        from repro.workloads import get_scenario
+        cols = get_scenario("conversation-poisson").generate_columns(
+            self.CFG.rate_rps, self.CFG.duration_s, self.CFG.seed)
+        as_cols = run_fleet_experiment(self.CFG, requests=cols)
+        self._same(as_cols, run_fleet_experiment(
+            self.CFG, requests=cols.to_requests()))
+        self._same(as_cols, run_fleet_experiment(self.CFG))
+
+    def test_scenario_with_only_generate_runs(self):
+        from repro.workloads import get_scenario, register_scenario, registry
+
+        class ListOnly:
+            name = "test-list-only"
+
+            def generate(self, rate_rps=60.0, duration_s=120.0, seed=0):
+                return get_scenario("conversation-poisson").generate(
+                    rate_rps, duration_s, seed)
+
+        register_scenario("test-list-only")(ListOnly)
+        try:
+            got = run_experiment(self.CFG.with_scenario("test-list-only"))
+        finally:
+            registry._REGISTRY.pop("test-list-only", None)
+        # the same trace under another name: only the labels differ
+        self._same(got, run_experiment(self.CFG),
+                   ignore=("scenario", "config_hash"))
+
+
 class TestScaleSmoke:
     def test_200_machines(self):
         """A 200-machine fleet through the vectorized engine at test

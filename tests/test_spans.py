@@ -135,6 +135,24 @@ def test_generate_span_counts_the_requests(fleet_profile):
     assert stats == {"requests": len(want)}
 
 
+def test_bin_span_counts_the_requests_converted(fleet_profile, tmp_path):
+    from repro.sim.fleetsim import run_fleet_experiment
+    _, spans, _ = fleet_profile
+    # run_experiment draws columns: nothing to convert
+    (_, _, _, stats), = _named(spans, "fleetsim.bin")
+    assert stats == {"converted": 0}
+    # a Request list is turned into columns once, in the bin span
+    reqs = get_scenario(FLEET_CFG.scenario).generate(
+        rate_rps=FLEET_CFG.rate_rps, duration_s=FLEET_CFG.duration_s,
+        seed=FLEET_CFG.seed)
+    cfg = FLEET_CFG.with_engine("fleet", backend="numpy")
+    _, spans = _profile(tmp_path,
+                        lambda: run_fleet_experiment(cfg, requests=reqs))
+    (_, _, _, stats), = _named(spans, "fleetsim.bin")
+    assert stats == {"converted": len(reqs)} and len(reqs) > 0
+    assert not _named(spans, "workloads.generate")
+
+
 def test_compile_span_is_the_compile_gauge(fleet_profile):
     _, spans, hub = fleet_profile
     compiles = _named(spans, "fleetsim.compile")

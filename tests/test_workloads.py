@@ -13,11 +13,11 @@ import numpy as np
 import pytest
 
 from repro.sim import ExperimentConfig, TaskIdAllocator, run_policy_sweep
-from repro.workloads import (ReplayScenario, Request, Scenario,
-                             available_scenarios, canonical_scenario_name,
-                             export_csv_str, get_scenario, load_csv, mixes,
-                             register_scenario, request_stats, splice,
-                             time_scale)
+from repro.workloads import (ReplayScenario, Request, RequestColumns,
+                             Scenario, available_scenarios,
+                             canonical_scenario_name, export_csv_str,
+                             get_scenario, load_csv, mixes, register_scenario,
+                             request_stats, splice, time_scale)
 from repro.workloads.arrivals import MMPPArrivals, PoissonArrivals
 
 # Fingerprint per scenario at (rate_rps=50, duration_s=30, seed=11):
@@ -131,6 +131,102 @@ class TestSeededGoldenDeterminism:
     def test_seed_changes_stream(self):
         sc = get_scenario("conversation-poisson")
         assert sc.generate(40, 10, seed=0) != sc.generate(40, 10, seed=1)
+
+
+def _oracle_sample(mix, rng):
+    """One request's token counts as the per-request generator drew them
+    before request generation filled columns: clipped with np.clip."""
+    if isinstance(mix, mixes.BlendedMix):
+        u = rng.random()
+        for edge, (_, component) in zip(mix._cum, mix.components):
+            if u <= edge:
+                return _oracle_sample(component, rng)
+        return _oracle_sample(mix.components[-1][1], rng)
+    n_in = int(np.clip(rng.lognormal(mix.input_logmean, mix.input_logstd),
+                       mix.input_min, mix.input_max))
+    n_out = int(np.clip(rng.lognormal(mix.output_logmean,
+                                      mix.output_logstd),
+                        mix.output_min, mix.output_max))
+    return n_in, n_out
+
+
+def _oracle_trace(sc: Scenario, rate_rps, duration_s, seed):
+    """A frozen copy of the per-request generation loop: one
+    `default_rng(seed)`, a gap then the token counts for each request."""
+    rng = np.random.default_rng(seed)
+    process = sc.arrival_factory(rate_rps, duration_s)
+    t, rows = 0.0, []
+    while True:
+        t += process.next_gap(rng, t)
+        if t >= duration_s:
+            break
+        rows.append((t, *_oracle_sample(sc.mix, rng)))
+    return rows
+
+
+ORACLE_SEEDS = (0, 11, 2**31 + 977)
+
+
+class TestRequestColumns:
+    """`generate_columns` draws what the per-request loop drew, bit for
+    bit, and `generate` is its `Request` list."""
+
+    @pytest.mark.parametrize("seed", ORACLE_SEEDS)
+    @pytest.mark.parametrize("name", sorted(SCENARIO_GOLD))
+    def test_columns_match_per_request_oracle(self, name, seed):
+        sc = get_scenario(name)
+        cols = sc.generate_columns(rate_rps=50, duration_s=30, seed=seed)
+        want = _oracle_trace(sc, 50, 30, seed)
+        assert len(cols) == len(want) > 0
+        assert cols.arrival_s.dtype == np.float64
+        assert cols.input_tokens.dtype == cols.output_tokens.dtype \
+            == np.int64
+        t, n_in, n_out = (list(c) for c in zip(*want))
+        assert cols.arrival_s.tolist() == t
+        assert cols.input_tokens.tolist() == n_in
+        assert cols.output_tokens.tolist() == n_out
+
+    @pytest.mark.parametrize("seed", ORACLE_SEEDS)
+    def test_clip_at_both_bounds_matches_np_clip(self, seed):
+        """A mix whose bounds cut deep into both tails takes every
+        branch of the clip on most requests."""
+        tight = mixes.LognormalMix(6.93, 0.85, 4.92, 0.95, input_min=900,
+                                   input_max=1100, output_min=100,
+                                   output_max=160)
+        sc = Scenario("tight", tight, lambda rate, dur: PoissonArrivals(rate))
+        cols = sc.generate_columns(rate_rps=200, duration_s=10, seed=seed)
+        want = _oracle_trace(sc, 200, 10, seed)
+        n_in = [r[1] for r in want]
+        assert {900, 1100} <= set(n_in)
+        assert cols.input_tokens.tolist() == n_in
+        assert cols.output_tokens.tolist() == [r[2] for r in want]
+
+    @pytest.mark.parametrize("name", sorted(SCENARIO_GOLD))
+    def test_generate_is_columns_as_requests(self, name):
+        sc = get_scenario(name)
+        cols = sc.generate_columns(40, 10, seed=7)
+        reqs = sc.generate(40, 10, seed=7)
+        assert reqs == cols.to_requests()
+        assert [r.req_id for r in reqs] == list(range(len(reqs)))
+        assert all(type(r.arrival_s) is float
+                   and type(r.input_tokens) is int
+                   and type(r.output_tokens) is int for r in reqs)
+        back = RequestColumns.from_requests(reqs)
+        for got, col in ((back.arrival_s, cols.arrival_s),
+                         (back.input_tokens, cols.input_tokens),
+                         (back.output_tokens, cols.output_tokens)):
+            assert got.dtype == col.dtype
+            assert np.array_equal(got, col)
+        assert back.to_requests() == reqs
+
+    def test_columns_are_read_only_and_one_length(self):
+        cols = RequestColumns([0.5, 1.5], [10, 20], [1, 2])
+        with pytest.raises(ValueError):
+            cols.arrival_s[0] = 9.0
+        assert len(cols) == 2
+        assert not RequestColumns([], [], [])
+        with pytest.raises(ValueError, match="one length"):
+            RequestColumns([0.5], [10, 20], [1])
 
 
 class TestLegacyBitExactness:
