@@ -1,8 +1,8 @@
 """Architecture registry: one module per assigned architecture.
 
 Each module defines FULL (the exact assigned config, cited) and SMOKE
-(reduced same-family variant: <=2 layers, d_model<=512, <=4 experts) for
-CPU smoke tests. Select via get_config(name) / get_smoke_config(name).
+(reduced same-family variant: <=5 layers, d_model<=512, <=4 experts
+held) for CPU smoke tests. Select via get_config(name) / get_smoke_config(name).
 """
 from __future__ import annotations
 
@@ -21,6 +21,7 @@ ARCH_IDS = [
     "granite_3_8b",
     "llama3_8b",
     "phi3_medium_14b",
+    "deepseek_v2_lite",
 ]
 
 # public --arch ids (dashes) -> module names
@@ -35,6 +36,7 @@ ALIASES = {
     "granite-3-8b": "granite_3_8b",
     "llama3-8b": "llama3_8b",
     "phi3-medium-14b": "phi3_medium_14b",
+    "deepseek-v2-lite": "deepseek_v2_lite",
 }
 
 

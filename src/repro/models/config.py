@@ -1,11 +1,19 @@
-"""Unified model configuration covering all ten assigned architectures."""
+"""Unified model configuration covering every architecture in `repro.configs`."""
 from __future__ import annotations
 
 import dataclasses
+import math
 
 
 def pad_to(x: int, mult: int) -> int:
     return ((x + mult - 1) // mult) * mult
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention-temperature factor 0.1 m ln(s) + 1 (1 for s <= 1)."""
+    if factor <= 1:
+        return 1.0
+    return 0.1 * mscale * math.log(factor) + 1.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -29,10 +37,24 @@ class ModelConfig:
     qk_nope_dim: int = 0
     qk_rope_dim: int = 0
     v_head_dim: int = 0
+    # --- YaRN rope scaling (DeepSeek-V2); 0 = plain rope ---
+    yarn_factor: float = 0.0
+    yarn_original_max_pos: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
     # --- MoE ---
-    num_experts: int = 0
+    num_experts: int = 0           # experts held here (the expert stack)
     experts_per_token: int = 0
-    moe_capacity_factor: float = 1.25
+    moe_capacity_factor: float = 1.25   # training loss only; serving is dropless
+    router_experts: int = 0        # experts routed over; 0 -> num_experts
+    expert_offset: int = 0         # router index of the first held expert
+    expert_d_ff: int = 0           # routed and shared expert width; 0 -> d_ff
+    shared_experts: int = 0        # always-on experts of width expert_d_ff
+    first_dense_layers: int = 0    # leading layers with a dense FFN of d_ff
+    moe_renormalize: bool = True   # top-k gates renormalised to sum to 1
+    moe_gate_scale: float = 1.0    # routed scaling factor on the gates
     # --- SSM (Mamba2 / SSD) ---
     ssm_state: int = 0             # N
     ssm_head_dim: int = 64         # P
@@ -86,6 +108,24 @@ class ModelConfig:
         return self.kv_lora_rank + self.qk_rope_dim
 
     @property
+    def routed_experts(self) -> int:
+        return self.router_experts or self.num_experts
+
+    @property
+    def moe_d_ff(self) -> int:
+        return self.expert_d_ff or self.d_ff
+
+    @property
+    def attn_scale(self) -> float:
+        """Softmax scale of MLA attention: 1/sqrt(qk head dim), times
+        YaRN's mscale squared where `yarn_mscale_all_dim` is set."""
+        scale = (self.qk_nope_dim + self.qk_rope_dim) ** -0.5
+        if self.yarn_factor and self.yarn_mscale_all_dim:
+            m = yarn_mscale(self.yarn_factor, self.yarn_mscale_all_dim)
+            scale = scale * m * m
+        return scale
+
+    @property
     def is_encdec(self) -> bool:
         return self.encoder_layers > 0
 
@@ -107,16 +147,19 @@ class ModelConfig:
         hd = self.resolved_head_dim if self.num_heads else 0
         n_attn = (self.num_heads * hd * d) * 2 + (self.num_kv_heads * hd * d) * 2
         if self.attn_type == "mla":
+            q_rank = self.q_lora_rank or d
             n_attn = (d * self.q_lora_rank
-                      + self.q_lora_rank * self.num_heads
+                      + q_rank * self.num_heads
                       * (self.qk_nope_dim + self.qk_rope_dim)
                       + d * (self.kv_lora_rank + self.qk_rope_dim)
                       + self.kv_lora_rank * self.num_heads
                       * (self.qk_nope_dim + self.v_head_dim)
                       + self.num_heads * self.v_head_dim * d)
         n_mlp = 3 * d * f
+        n_dense_mlp = n_mlp
         if self.num_experts:
-            n_mlp = self.num_experts * 3 * d * f + d * self.num_experts
+            e, fe = self.routed_experts, self.moe_d_ff
+            n_mlp = (e + self.shared_experts) * 3 * d * fe + d * e
         n_ssm = 0
         if self.attn_type == "none" or self.family in ("ssm", "hybrid"):
             di, g, n, h = self.d_inner, self.ssm_groups, self.ssm_state, self.ssm_heads
@@ -131,6 +174,7 @@ class ModelConfig:
         else:
             per_layer = n_attn + n_mlp
         total = self.num_layers * per_layer + 2 * emb
+        total -= self.first_dense_layers * (n_mlp - n_dense_mlp)
         if self.family == "hybrid":
             total += n_attn + 3 * d * f  # the single shared attn+mlp block
         if self.is_encdec:
@@ -143,10 +187,11 @@ class ModelConfig:
         """Activated parameters per token (MoE top-k) for 6·N_active·D."""
         if not self.num_experts:
             return self.param_count()
-        d, f = self.d_model, self.d_ff
-        dense_moe = self.num_experts * 3 * d * f
+        d, f = self.d_model, self.moe_d_ff
+        dense_moe = self.routed_experts * 3 * d * f
         active_moe = self.experts_per_token * 3 * d * f
-        return self.param_count() - self.num_layers * (dense_moe - active_moe)
+        moe_layers = self.num_layers - self.first_dense_layers
+        return self.param_count() - moe_layers * (dense_moe - active_moe)
 
 
 @dataclasses.dataclass(frozen=True)

@@ -1,8 +1,11 @@
 """Common neural layers: RMSNorm, RoPE, SwiGLU, embeddings. Pure JAX."""
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.models.config import ModelConfig
 
@@ -21,17 +24,48 @@ def rope_frequencies(head_dim: int, theta: float) -> jax.Array:
                             / head_dim))
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
+def yarn_frequencies(head_dim: int, theta: float, factor: float,
+                     original_max_pos: int, beta_fast: float,
+                     beta_slow: float) -> np.ndarray:
+    """YaRN inverse frequencies (arXiv:2309.00071, as DeepSeek-V2 uses
+    them): dimensions that turn more than `beta_fast` times over the
+    original context keep their frequency, those that turn fewer than
+    `beta_slow` times are divided by `factor`, with a linear ramp
+    between. Shape (head_dim//2,), float32."""
+    def correction_dim(rotations):
+        return (head_dim * math.log(original_max_pos
+                                    / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+    low = max(math.floor(correction_dim(beta_fast)), 0)
+    high = min(math.ceil(correction_dim(beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    exps = np.arange(0, head_dim, 2, dtype=np.float32) / head_dim
+    extra = 1.0 / theta ** exps
+    inter = 1.0 / (factor * theta ** exps)
+    ramp = np.clip((np.arange(head_dim // 2, dtype=np.float32) - low)
+                   / (high - low), 0.0, 1.0)
+    keep = 1.0 - ramp
+    return (inter * (1.0 - keep) + extra * keep).astype(np.float32)
+
+
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
+               inv_freq=None, mscale: float = 1.0) -> jax.Array:
     """Rotary position embedding.
 
     x: (..., S, H, D) with D even; positions: broadcastable to (..., S).
     Uses the half-split convention (rotate_half), matching Llama.
+    `inv_freq` replaces the plain frequencies (YaRN), and `mscale`
+    scales cos and sin.
     """
     d = x.shape[-1]
-    inv_freq = rope_frequencies(d, theta)                  # (D/2,)
+    if inv_freq is None:
+        inv_freq = rope_frequencies(d, theta)              # (D/2,)
     angles = positions[..., None].astype(jnp.float32) * inv_freq  # (...,S,D/2)
     cos = jnp.cos(angles)[..., None, :]                    # (...,S,1,D/2)
     sin = jnp.sin(angles)[..., None, :]
+    if mscale != 1.0:
+        cos, sin = cos * mscale, sin * mscale
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)

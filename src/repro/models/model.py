@@ -1,12 +1,17 @@
-"""Config-driven model zoo: init / train-loss / prefill / decode for all
-ten assigned architectures (dense GQA, MLA, MoE+SWA, Mamba2 SSD, Zamba2
+"""Config-driven model zoo: init / train-loss / prefill / decode for every
+architecture in `repro.configs` (dense GQA, MLA, MoE+SWA, DeepSeek-style
+MoE with shared experts and leading dense layers, Mamba2 SSD, Zamba2
 hybrid, Seamless enc-dec audio, InternVL2 VLM).
 
 Conventions:
   * params are plain nested dicts; per-layer params are stacked on a
     leading `num_layers` axis and iterated with lax.scan (compact HLO for
-    the 80 dry-run compiles).
-  * caches are dicts of stacked arrays with a scalar `pos` (valid tokens).
+    the 80 dry-run compiles). A model with `first_dense_layers` has two
+    stacks: `dense_layers` (dense FFN) and then `layers` (MoE).
+  * caches are dicts of stacked arrays with a scalar `pos` (valid tokens);
+    the `dense_layers` stack's entries carry the prefix `dense_`. For an
+    MoE model, `prefill` and `decode_step` also return the call's routing
+    counts of the held experts under `moe` (see `moe.zero_counts`).
   * modality frontends (ViT / audio codec) are STUBS per the assignment:
     callers pass precomputed `embeds` of shape (B, frontend_tokens, D).
 """
@@ -20,8 +25,9 @@ import jax.numpy as jnp
 from repro.models import attention as attn
 from repro.models import mamba2, moe
 from repro.models.config import ModelConfig
+from repro.models.config import yarn_mscale
 from repro.models.layers import (apply_rope, cross_entropy_loss, embed,
-                                 rms_norm, swiglu, unembed)
+                                 rms_norm, swiglu, unembed, yarn_frequencies)
 
 INIT_STD = 0.02
 MOE_AUX_COEF = 0.01
@@ -45,10 +51,14 @@ def _init_attn(key, cfg: ModelConfig, dt) -> dict:
     if cfg.attn_type == "mla":
         qk_dim = cfg.qk_nope_dim + cfg.qk_rope_dim
         ks = jax.random.split(key, 6)
+        if cfg.q_lora_rank:
+            q = {"q_down": _dense(ks[0], (d, cfg.q_lora_rank), dt),
+                 "q_ln": jnp.ones((cfg.q_lora_rank,), dt),
+                 "q_up": _dense(ks[1], (cfg.q_lora_rank, h * qk_dim), dt)}
+        else:                      # queries straight from x (DeepSeek-V2-Lite)
+            q = {"wq": _dense(ks[1], (d, h * qk_dim), dt)}
         return {
-            "q_down": _dense(ks[0], (d, cfg.q_lora_rank), dt),
-            "q_ln": jnp.ones((cfg.q_lora_rank,), dt),
-            "q_up": _dense(ks[1], (cfg.q_lora_rank, h * qk_dim), dt),
+            **q,
             "kv_down": _dense(ks[2], (d, cfg.kv_lora_rank + cfg.qk_rope_dim), dt),
             "kv_ln": jnp.ones((cfg.kv_lora_rank,), dt),
             "k_up": _dense(ks[3], (cfg.kv_lora_rank, h * cfg.qk_nope_dim), dt),
@@ -63,17 +73,24 @@ def _init_attn(key, cfg: ModelConfig, dt) -> dict:
     }
 
 
-def _init_mlp(key, cfg: ModelConfig, dt) -> dict:
+def _init_mlp(key, cfg: ModelConfig, dt, dense: bool = False) -> dict:
     d, f = cfg.d_model, cfg.d_ff
     ks = jax.random.split(key, 4)
-    if cfg.num_experts:
-        e = cfg.num_experts
-        return {
-            "router": _dense(ks[0], (d, e), jnp.float32),
+    if cfg.num_experts and not dense:
+        e, f = cfg.num_experts, cfg.moe_d_ff
+        p = {
+            "router": _dense(ks[0], (d, cfg.routed_experts), jnp.float32),
             "w_gate": _dense(ks[1], (e, d, f), dt),
             "w_up": _dense(ks[2], (e, d, f), dt),
             "w_down": _dense(ks[3], (e, f, d), dt),
         }
+        if cfg.shared_experts:
+            fs = cfg.shared_experts * f
+            kg, ku, kd = jax.random.split(jax.random.fold_in(key, 1), 3)
+            p["shared"] = {"w_gate": _dense(kg, (d, fs), dt),
+                           "w_up": _dense(ku, (d, fs), dt),
+                           "w_down": _dense(kd, (fs, d), dt)}
+        return p
     return {
         "w_gate": _dense(ks[0], (d, f), dt),
         "w_up": _dense(ks[1], (d, f), dt),
@@ -81,13 +98,14 @@ def _init_mlp(key, cfg: ModelConfig, dt) -> dict:
     }
 
 
-def _init_block(key, cfg: ModelConfig, dt, cross: bool = False) -> dict:
+def _init_block(key, cfg: ModelConfig, dt, cross: bool = False,
+                dense: bool = False) -> dict:
     k1, k2, k3 = jax.random.split(key, 3)
     p = {
         "ln1": jnp.ones((cfg.d_model,), dt),
         "attn": _init_attn(k1, cfg, dt),
         "ln2": jnp.ones((cfg.d_model,), dt),
-        "mlp": _init_mlp(k2, cfg, dt),
+        "mlp": _init_mlp(k2, cfg, dt, dense),
     }
     if cross:
         p["ln_x"] = jnp.ones((cfg.d_model,), dt)
@@ -147,12 +165,38 @@ class Model:
                 lambda k: _init_block(k, cfg, dt))(ek)
             params["enc_final_ln"] = jnp.ones((cfg.d_model,), dt)
         else:  # dense / moe / vlm
+            nd = cfg.first_dense_layers
+            if nd:
+                params["dense_layers"] = jax.vmap(
+                    lambda k: _init_block(k, cfg, dt, dense=True))(lk[:nd])
             params["layers"] = jax.vmap(
-                lambda k: _init_block(k, cfg, dt))(lk)
+                lambda k: _init_block(k, cfg, dt))(lk[nd:])
         return params
 
     def abstract_params(self, seed: int = 0):
         return jax.eval_shape(self.init, jax.random.key(seed))
+
+    @staticmethod
+    def _stacks(params) -> list[tuple[str, dict]]:
+        """(cache-key prefix, stacked layer params), in depth order."""
+        stacks = [("", params["layers"])]
+        if "dense_layers" in params:
+            stacks.insert(0, ("dense_", params["dense_layers"]))
+        return stacks
+
+    def _rope(self, x, positions):
+        """Rotary embedding with the config's frequencies: plain, or YaRN
+        (frequencies and the cos/sin mscale of DeepSeek-V2)."""
+        cfg = self.cfg
+        if not cfg.yarn_factor:
+            return apply_rope(x, positions, cfg.rope_theta)
+        inv_freq = yarn_frequencies(
+            x.shape[-1], cfg.rope_theta, cfg.yarn_factor,
+            cfg.yarn_original_max_pos, cfg.yarn_beta_fast,
+            cfg.yarn_beta_slow)
+        mscale = (yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale)
+                  / yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim))
+        return apply_rope(x, positions, cfg.rope_theta, inv_freq, mscale)
 
     # ====================== attention sub-blocks ====================== #
     def _gqa_qkv(self, h, ap, positions):
@@ -165,20 +209,23 @@ class Model:
             b, s, cfg.num_kv_heads, hd)
         v = jnp.einsum("bsd,de->bse", h, ap["wv"]).reshape(
             b, s, cfg.num_kv_heads, hd)
-        q = apply_rope(q, positions, cfg.rope_theta)
-        k = apply_rope(k, positions, cfg.rope_theta)
+        q = self._rope(q, positions)
+        k = self._rope(k, positions)
         return q, k, v
 
     def _mla_q(self, h, ap, positions):
         cfg = self.cfg
         b, s, _ = h.shape
         qk_dim = cfg.qk_nope_dim + cfg.qk_rope_dim
-        ql = rms_norm(jnp.einsum("bsd,dr->bsr", h, ap["q_down"]),
-                      ap["q_ln"], cfg.norm_eps)
-        q = jnp.einsum("bsr,re->bse", ql, ap["q_up"]).reshape(
-            b, s, cfg.num_heads, qk_dim)
+        if "wq" in ap:
+            q = jnp.einsum("bsd,de->bse", h, ap["wq"])
+        else:
+            ql = rms_norm(jnp.einsum("bsd,dr->bsr", h, ap["q_down"]),
+                          ap["q_ln"], cfg.norm_eps)
+            q = jnp.einsum("bsr,re->bse", ql, ap["q_up"])
+        q = q.reshape(b, s, cfg.num_heads, qk_dim)
         q_nope, q_rope = jnp.split(q, [cfg.qk_nope_dim], axis=-1)
-        q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
+        q_rope = self._rope(q_rope, positions)
         return jnp.concatenate([q_nope, q_rope], axis=-1)
 
     def _mla_latent(self, h, ap, positions):
@@ -187,8 +234,7 @@ class Model:
         lat = jnp.einsum("bsd,dr->bsr", h, ap["kv_down"])
         c_kv, k_rope = jnp.split(lat, [cfg.kv_lora_rank], axis=-1)
         c_kv = rms_norm(c_kv, ap["kv_ln"], cfg.norm_eps)
-        k_rope = apply_rope(k_rope[:, :, None, :], positions,
-                            cfg.rope_theta)[:, :, 0]
+        k_rope = self._rope(k_rope[:, :, None, :], positions)[:, :, 0]
         return jnp.concatenate([c_kv, k_rope], axis=-1)
 
     def _mla_kv_from_latent(self, latent, ap):
@@ -213,7 +259,7 @@ class Model:
             q = self._mla_q(hn, lp["attn"], positions)
             latent = self._mla_latent(hn, lp["attn"], positions)
             k, v = self._mla_kv_from_latent(latent, lp["attn"])
-            scale = (cfg.qk_nope_dim + cfg.qk_rope_dim) ** -0.5
+            scale = cfg.attn_scale
             o = attn.self_attention(q, k, v, causal=causal,
                                     window=cfg.sliding_window,
                                     q_offset=q_offset, scale=scale,
@@ -227,14 +273,28 @@ class Model:
         o = o.reshape(*o.shape[:2], -1)
         return h + jnp.einsum("bse,ed->bsd", o, lp["attn"]["wo"]), (k, v)
 
-    def _mlp(self, h, lp):
+    def _mlp(self, h, lp, dropless=False, valid=None, experts=None):
+        """FFN sub-block. Returns (h, extra). In training, `extra` is the
+        MoE aux loss (0.0 for a dense FFN); with `dropless` (serving) it
+        is the held experts' routing counts (None for a dense FFN),
+        `valid` (B,) marks the rows routed at all, and `experts` is
+        (the stack's expert matrices, this layer's index) where the
+        scan leaves them out of `lp` (`_split_experts`)."""
         cfg = self.cfg
         hn = rms_norm(h, lp["ln2"], cfg.norm_eps)
-        if cfg.num_experts:
-            y, aux = moe.moe_ffn_sharded(hn, lp["mlp"], cfg)
-            return h + y, aux
-        return h + swiglu(hn, lp["mlp"]["w_gate"], lp["mlp"]["w_up"],
-                          lp["mlp"]["w_down"]), 0.0
+        mp = lp["mlp"]
+        if "router" not in mp:
+            return h + swiglu(hn, mp["w_gate"], mp["w_up"],
+                              mp["w_down"]), (None if dropless else 0.0)
+        if dropless:
+            layer = None
+            if experts is not None:
+                stack, layer = experts
+                mp = {**mp, **stack}
+            y, extra = moe.moe_ffn_dropless(hn, mp, cfg, valid, layer)
+        else:
+            y, extra = moe.moe_ffn_sharded(hn, mp, cfg)
+        return h + y + moe.shared_ffn(hn, mp), extra
 
     def _maybe_seq_parallel(self, h):
         """Megatron-SP (§Perf): pin the residual stream to a
@@ -357,8 +417,10 @@ class Model:
             return (hh, aux + a), None
         if cfg.remat:
             body = jax.checkpoint(body)
-        (h, aux), _ = jax.lax.scan(body, (h, 0.0), params["layers"],
-                                   unroll=cfg.scan_unroll)
+        aux = 0.0
+        for _, layers in self._stacks(params):
+            (h, aux), _ = jax.lax.scan(body, (h, aux), layers,
+                                       unroll=cfg.scan_unroll)
         return h, aux
 
     def _hybrid_forward(self, params, h, positions):
@@ -474,29 +536,68 @@ class Model:
         max_len = max_len or s
         positions = jnp.arange(s)[None, :]
 
-        def body(carry, lp):
-            hh, aux = carry
-            hh, kv, a = self._block_full(hh, lp, positions)
-            return (hh, aux + a), kv
-
-        (h, _), kvs = jax.lax.scan(body, (h, 0.0), params["layers"], unroll=cfg.scan_unroll)
+        cache, counts = {}, None
+        for prefix, layers in self._stacks(params):
+            h, kvs, c = self._prefill_stack(layers, h, positions)
+            counts = c if c is not None else counts
+            cache.update({prefix + k: v for k, v in
+                          self._stack_cache(kvs, s, max_len).items()})
         h = rms_norm(h, params["final_ln"], cfg.norm_eps)
         logits = unembed(h[:, -1:], params["unembed"], cfg)
+        cache["pos"] = jnp.int32(s)
+        if counts is not None:
+            cache["moe"] = counts
+        return logits, cache
 
+    @staticmethod
+    def _split_experts(layers):
+        """(the stack without its routed expert matrices, a function that
+        gives `_mlp` those matrices whole with layer i's index, scanned
+        layer indices). The grouped matmul reads a layer's experts out of
+        the whole stack; a scan slicing them out would copy them all,
+        every layer and step. For a dense stack: (layers, None, None)."""
+        mp = layers["mlp"]
+        if "router" not in mp:
+            return layers, None, None
+        stack = {k: mp[k] for k in ("w_gate", "w_up", "w_down")}
+        rest = {**layers, "mlp": {k: v for k, v in mp.items()
+                                  if k not in stack}}
+        n = stack["w_gate"].shape[0]
+        return rest, (lambda i: (stack, i)), jnp.arange(n)
+
+    def _prefill_stack(self, layers, h, positions):
+        """One stack of blocks over the whole prompt. Returns (h, each
+        layer's K/V or latent, the held experts' routing counts or None)."""
+        counts = moe.zero_counts(self.cfg) if "router" in layers["mlp"] \
+            else None
+        layers, experts, index = self._split_experts(layers)
+
+        def body(carry, xs):
+            (hh, counts), (lp, i) = carry, xs
+            hh, kv = self._attn_full(hh, lp, positions)
+            hh, c = self._mlp(hh, lp, dropless=True,
+                              experts=experts and experts(i))
+            return (hh, moe.add_counts(counts, c)), kv
+
+        (h, counts), kvs = jax.lax.scan(body, (h, counts), (layers, index),
+                                        unroll=self.cfg.scan_unroll)
+        return h, kvs, counts
+
+    def _stack_cache(self, kvs, s: int, max_len: int) -> dict:
+        """A stack's cache entries, padded from `s` tokens to `max_len`."""
+        cfg = self.cfg
         pad = max_len - s
         if cfg.attn_type == "mla":
-            latent = jnp.pad(kvs, ((0, 0), (0, 0), (0, pad), (0, 0)))
-            cache = {"latent": latent, "pos": jnp.int32(s)}
+            return {"latent": jnp.pad(kvs, ((0, 0), (0, 0), (0, pad),
+                                            (0, 0)))}
+        k, v = kvs
+        if cfg.swa_ring and cfg.sliding_window:
+            k = self._to_ring(k, s)
+            v = self._to_ring(v, s)
         else:
-            k, v = kvs
-            if cfg.swa_ring and cfg.sliding_window:
-                k = self._to_ring(k, s)
-                v = self._to_ring(v, s)
-            else:
-                k = jnp.pad(k, ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0)))
-                v = jnp.pad(v, ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0)))
-            cache = {"k": k, "v": v, "pos": jnp.int32(s)}
-        return logits, cache
+            k = jnp.pad(k, ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0)))
+            v = jnp.pad(v, ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0)))
+        return {"k": k, "v": v}
 
     def _to_ring(self, k, s):
         """§Perf: convert stacked full-length K/V (L,B,S,H,D) into a
@@ -627,9 +728,12 @@ class Model:
         return logits, cache
 
     # ========================= serving: decode ======================== #
-    def decode_step(self, params: dict, cache: dict,
-                    token: jax.Array) -> tuple[jax.Array, dict]:
-        """One decode step. token: (B, 1) int32. Returns (logits, cache)."""
+    def decode_step(self, params: dict, cache: dict, token: jax.Array,
+                    active: jax.Array | None = None
+                    ) -> tuple[jax.Array, dict]:
+        """One decode step. token: (B, 1) int32; `active` (B,) marks the
+        rows whose tokens the MoE layers route (all when None). Returns
+        (logits, cache)."""
         cfg = self.cfg
         cache = dict(cache)
         cache["pos"] = jnp.asarray(cache["pos"], jnp.int32)
@@ -645,29 +749,54 @@ class Model:
         positions = (jnp.full((1, 1), pos, jnp.int32) if pos.ndim == 0
                      else pos[:, None])
 
-        if cfg.attn_type == "mla":
-            def body(carry, xs):
-                lp, lat = xs
-                hh = carry
-                hh, lat = self._mla_decode_block(hh, lp, lat, pos, positions)
-                return hh, lat
-            h, latents = jax.lax.scan(body, h,
-                                      (params["layers"], cache["latent"]), unroll=cfg.scan_unroll)
-            new_cache = {"latent": latents, "pos": pos + 1}
-        else:
-            def body(carry, xs):
-                lp, ck, cv = xs
-                hh = carry
-                hh, nk, nv = self._gqa_decode_block(hh, lp, ck, cv, pos,
-                                                    positions)
-                return hh, (nk, nv)
-            h, (nks, nvs) = jax.lax.scan(
-                body, h, (params["layers"], cache["k"], cache["v"]), unroll=cfg.scan_unroll)
-            new_cache = {"k": nks, "v": nvs, "pos": pos + 1}
+        new_cache, counts = {}, None
+        for prefix, layers in self._stacks(params):
+            h, entries, c = self._decode_stack(
+                layers, {k: cache[prefix + k] for k in
+                         (("latent",) if cfg.attn_type == "mla"
+                          else ("k", "v"))},
+                h, pos, positions, active)
+            counts = c if c is not None else counts
+            new_cache.update({prefix + k: v for k, v in entries.items()})
+        new_cache["pos"] = pos + 1
+        if counts is not None:
+            new_cache["moe"] = counts
         h = rms_norm(h, params["final_ln"], cfg.norm_eps)
         return unembed(h, params["unembed"], cfg), new_cache
 
-    def _gqa_decode_block(self, h, lp, ck, cv, pos, positions):
+    def _decode_stack(self, layers, cache, h, pos, positions, valid):
+        """One stack of blocks for one token per row. Returns (h, the
+        stack's new cache entries, routing counts or None)."""
+        cfg = self.cfg
+        counts = moe.zero_counts(cfg) if "router" in layers["mlp"] else None
+        layers, experts, index = self._split_experts(layers)
+        if cfg.attn_type == "mla":
+            def body(carry, xs):
+                lp, lat, i = xs
+                hh, counts = carry
+                hh, lat, c = self._mla_decode_block(
+                    hh, lp, lat, pos, positions, valid,
+                    experts and experts(i))
+                return (hh, moe.add_counts(counts, c)), lat
+            (h, counts), latents = jax.lax.scan(
+                body, (h, counts), (layers, cache["latent"], index),
+                unroll=cfg.scan_unroll)
+            return h, {"latent": latents}, counts
+
+        def body(carry, xs):
+            lp, ck, cv, i = xs
+            hh, counts = carry
+            hh, nk, nv, c = self._gqa_decode_block(
+                hh, lp, ck, cv, pos, positions, valid,
+                experts and experts(i))
+            return (hh, moe.add_counts(counts, c)), (nk, nv)
+        (h, counts), (nks, nvs) = jax.lax.scan(
+            body, (h, counts), (layers, cache["k"], cache["v"], index),
+            unroll=cfg.scan_unroll)
+        return h, {"k": nks, "v": nvs}, counts
+
+    def _gqa_decode_block(self, h, lp, ck, cv, pos, positions, valid=None,
+                          experts=None):
         cfg = self.cfg
         hn = rms_norm(h, lp["ln1"], cfg.norm_eps)
         q, k, v = self._gqa_qkv(hn, lp["attn"], positions)
@@ -689,10 +818,12 @@ class Model:
             o = attn.decode_attention(q, ck, cv, pos_eff, window=window)
         o = o.reshape(*o.shape[:2], -1)
         h = h + jnp.einsum("bse,ed->bsd", o, lp["attn"]["wo"])
-        h, _ = self._mlp(h, lp)
-        return h, ck, cv
+        h, counts = self._mlp(h, lp, dropless=True, valid=valid,
+                              experts=experts)
+        return h, ck, cv, counts
 
-    def _mla_decode_block(self, h, lp, latent_cache, pos, positions):
+    def _mla_decode_block(self, h, lp, latent_cache, pos, positions,
+                          valid=None, experts=None):
         """MLA decode: append this token's latent, expand K/V from the
         latent cache (naive materialization — see §Perf for the absorbed
         variant)."""
@@ -708,7 +839,7 @@ class Model:
             latent_cache = latent_cache.at[
                 jnp.arange(latent_cache.shape[0]), pos].set(lat_new[:, 0])
         latent_cache = self._pin_cache(latent_cache, kind="latent")
-        scale = (cfg.qk_nope_dim + cfg.qk_rope_dim) ** -0.5
+        scale = cfg.attn_scale
         if cfg.mla_absorb:
             o = self._mla_absorbed_attention(q, latent_cache, lp["attn"],
                                              pos, scale)
@@ -717,8 +848,9 @@ class Model:
             o = attn.decode_attention(q, k, v, pos + 1, scale=scale)
         o = o.reshape(*o.shape[:2], -1)
         h = h + jnp.einsum("bse,ed->bsd", o, lp["attn"]["wo"])
-        h, _ = self._mlp(h, lp)
-        return h, latent_cache
+        h, counts = self._mlp(h, lp, dropless=True, valid=valid,
+                              experts=experts)
+        return h, latent_cache, counts
 
     def _mla_absorbed_attention(self, q, latent_cache, ap, pos, scale):
         """Absorbed-matmul MLA decode (§Perf): fold k_up into the query
@@ -796,8 +928,8 @@ class Model:
                 return cc, (nc["conv"], nc["state"])
             c, (convs, states) = jax.lax.scan(
                 inner, c, (glp, ssm_c["conv"], ssm_c["state"]), unroll=cfg.scan_unroll)
-            c, nk, nv = self._gqa_decode_block(c, shared, attn_c["k"],
-                                               attn_c["v"], pos, positions)
+            c, nk, nv, _ = self._gqa_decode_block(
+                c, shared, attn_c["k"], attn_c["v"], pos, positions)
             return c, ({"conv": convs, "state": states},
                        {"k": nk, "v": nv})
 

@@ -16,6 +16,14 @@ and `engine.splice` inside it, then `engine.decode` (active and total
 slots), `engine.tokens` (the per-slot token reads and retirements) and
 `engine.core_manager` (each call into the core manager); see
 `repro.telemetry.spans`.
+
+For an MoE model the prefill and decode programs also accumulate, on
+the device, the routing counts of the experts the model holds: tokens
+routed to each held expert (prefill and decode apart) and non-empty
+(step, layer, held expert) groups. Nothing reads them per step;
+`moe_counts()` reads them once, on request, and exports them through
+the telemetry hub as `engine/moe_expert_tokens` and
+`engine/moe_expert_groups`.
 """
 from __future__ import annotations
 
@@ -103,15 +111,35 @@ class InferenceEngine:
             jax.ShapeDtypeStruct((b, 1), jnp.int32))
         cache = jax.tree.map(lambda a: jnp.zeros(a.shape, a.dtype), abstract)
         cache["pos"] = jnp.zeros((b,), jnp.int32)
+        if "moe" in cache:
+            # accumulated routing counts, row 0 prefill and row 1 decode
+            cache["moe"] = jax.tree.map(
+                lambda a: jnp.zeros((2, *a.shape), a.dtype), cache["moe"])
         return cache
 
-    def _prefill_fn(self, params, tokens, max_len):
-        return self.model.prefill(params, tokens, None, max_len=max_len)
+    @staticmethod
+    def _count(total, counts, phase: int):
+        """Add one call's routing counts to row `phase` (0 prefill,
+        1 decode) of the accumulated counts."""
+        return jax.tree.map(lambda t, c: t.at[phase].add(c), total, counts)
+
+    def _prefill_fn(self, params, tokens, max_len, moe=None):
+        """The prompt's logits and cache; with the engine's accumulated
+        routing counts `moe`, the cache's `moe` is them plus this
+        prefill's."""
+        logits, cache = self.model.prefill(params, tokens, None,
+                                           max_len=max_len)
+        if moe is not None:
+            cache["moe"] = self._count(moe, cache["moe"], 0)
+        return logits, cache
 
     def _decode_fn(self, params, cache, tokens, active):
-        logits, new_cache = self.model.decode_step(params, cache, tokens)
+        logits, new_cache = self.model.decode_step(params, cache, tokens,
+                                                   active)
         # inactive slots must not advance their position
         new_cache["pos"] = jnp.where(active, new_cache["pos"], cache["pos"])
+        if "moe" in cache:
+            new_cache["moe"] = self._count(cache["moe"], new_cache["moe"], 1)
         return logits, new_cache
 
     # ----------------------------- host API ---------------------------- #
@@ -160,7 +188,8 @@ class InferenceEngine:
         self._cpu_task("alloc_memory")
         with span("engine.prefill"):
             toks = jnp.asarray(req.prompt, jnp.int32)[None, :]
-            logits, pcache = self._prefill(self.params, toks, self.max_len)
+            logits, pcache = self._prefill(self.params, toks, self.max_len,
+                                           self.cache.get("moe"))
 
         # splice the single-row prefill cache into slot i
         def splice(big, small):
@@ -173,6 +202,8 @@ class InferenceEngine:
                 if key == "pos":
                     new_cache[key] = self.cache[key].at[i].set(
                         len(req.prompt))
+                elif key == "moe":      # counts, not a row: the prefill's
+                    new_cache[key] = pcache[key]
                 else:
                     new_cache[key] = jax.tree.map(
                         splice, self.cache[key], pcache[key])
@@ -238,6 +269,25 @@ class InferenceEngine:
         raise RuntimeError("engine did not drain")
 
     # -------------------------- observability -------------------------- #
+    def moe_counts(self) -> dict | None:
+        """The held experts' routing counts so far, read from the device
+        once: `tokens` (2, held experts) and `groups` (2,), row 0 for
+        prefill and row 1 for decode. With a telemetry hub they are also
+        recorded as the timelines `engine/moe_expert_tokens` (prefill
+        then decode tokens per held expert) and `engine/moe_expert_groups`.
+        None for a model without experts."""
+        if "moe" not in self.cache:
+            return None
+        counts = {k: np.asarray(v) for k, v in jax.device_get(
+            self.cache["moe"]).items()}
+        if self.telemetry is not None:
+            t = self._now()
+            self.telemetry.timeline("engine/moe_expert_tokens").record(
+                t, counts["tokens"].ravel().tolist())
+            self.telemetry.timeline("engine/moe_expert_groups").record(
+                t, counts["groups"].tolist())
+        return counts
+
     def host_cpu_report(self) -> dict:
         m = self.core_manager
         return {

@@ -15,6 +15,7 @@ ASSIGNED = {
     "granite-3-8b": (40, 4096, 32, 8, 12800, 49155),
     "llama3-8b": (32, 4096, 32, 8, 14336, 128256),
     "phi3-medium-14b": (40, 5120, 40, 10, 17920, 100352),
+    "deepseek-v2-lite": (27, 2048, 16, 16, 10944, 102400),
 }
 
 
@@ -32,7 +33,8 @@ def test_exact_assigned_numbers(arch):
 
 
 def test_all_ten_present():
-    assert len(all_arch_names()) == 10
+    # the ten assigned architectures and DeepSeek-V2-Lite
+    assert len(all_arch_names()) == 11
     assert set(all_arch_names()) == set(ASSIGNED)
 
 
@@ -50,6 +52,18 @@ def test_family_specifics():
     assert get_config("seamless-m4t-large-v2").encoder_layers == 24
     assert get_config("internvl2-2b").frontend == "vision"
     assert get_config("seamless-m4t-large-v2").frontend == "audio"
+    ds = get_config("deepseek-v2-lite")
+    assert (ds.router_experts, ds.num_experts, ds.expert_offset) == (64, 16, 0)
+    assert (ds.experts_per_token, ds.expert_d_ff, ds.shared_experts) == \
+        (6, 1408, 2)
+    assert ds.first_dense_layers == 1 and not ds.moe_renormalize
+    assert ds.moe_gate_scale == 1.0
+    assert (ds.q_lora_rank, ds.kv_lora_rank, ds.qk_nope_dim, ds.qk_rope_dim,
+            ds.v_head_dim) == (0, 512, 128, 64, 128)
+    assert (ds.yarn_factor, ds.yarn_original_max_pos, ds.yarn_beta_fast,
+            ds.yarn_beta_slow, ds.yarn_mscale, ds.yarn_mscale_all_dim) == \
+        (40.0, 4096, 32.0, 1.0, 0.707, 0.707)
+    assert ds.norm_eps == 1e-6 and ds.mla_absorb
 
 
 @pytest.mark.parametrize("arch", list(ASSIGNED))
@@ -70,7 +84,7 @@ def test_param_counts_sane(arch):
         "mamba2-2.7b": 2.7e9, "seamless-m4t-large-v2": 2.3e9,
         "minicpm3-4b": 4.0e9, "mixtral-8x22b": 141e9, "zamba2-7b": 7.5e9,
         "granite-3-8b": 8.1e9, "llama3-8b": 8.0e9,
-        "phi3-medium-14b": 14e9,
+        "phi3-medium-14b": 14e9, "deepseek-v2-lite": 15.7e9,
     }[arch]
     got = get_config(arch).param_count()
     assert 0.5 * expected_b < got < 2.0 * expected_b, got
