@@ -13,11 +13,11 @@ experts 0-15 (`num_experts` 16 of `router_experts` 64); the router
 scores all 64 and the layer computes its own experts' part. The
 analytic `param_count` is of the whole model.
 
-Decode uses the absorbed MLA form (`mla_absorb`), as DeepSeek-V2 §2.1
-does at inference. HF's rope permutes the rope dimensions from
-interleaved to half-split pairs; that is a fixed reordering of the
-rope columns of `wq` and `kv_down`, so the program keeps the half-split
-convention.
+Decode attends in the latent space, in the absorbed form of
+DeepSeek-V2 §2.1, as for every MLA model. HF's rope permutes the rope
+dimensions from interleaved to half-split pairs; that is a fixed
+reordering of the rope columns of `wq` and `kv_down`, so the program
+keeps the half-split convention.
 """
 import dataclasses
 
@@ -54,7 +54,6 @@ FULL = ModelConfig(
     first_dense_layers=1,
     moe_renormalize=False,
     moe_gate_scale=1.0,
-    mla_absorb=True,
     norm_eps=1e-6,
     citation="hf:deepseek-ai/DeepSeek-V2-Lite (config.json); arXiv:2405.04434",
 )

@@ -32,9 +32,9 @@ def resolve_config(arch: str, shape_name: str,
     cfg = mod.LONG_CONTEXT if shape_name == "long_500k" else mod.FULL
     if variant == "optimized":
         # beyond-paper §Perf knobs (see EXPERIMENTS.md): chunked online-
-        # softmax attention, absorbed MLA decode, sequence-parallel
-        # residuals, ZeRO-1 optimizer sharding.
-        cfg = dataclasses.replace(cfg, attn_chunk=2048, mla_absorb=True,
+        # softmax attention, sequence-parallel residuals, ZeRO-1
+        # optimizer sharding.
+        cfg = dataclasses.replace(cfg, attn_chunk=2048,
                                   seq_parallel=True, zero1=True,
                                   pin_cache_sharding=True, swa_ring=True)
     return cfg
@@ -278,8 +278,8 @@ def run_one(arch: str, shape_name: str, multi_pod: bool,
     rec = {
         "arch": arch,
         "shape": shape_name,
-        "variant": ("optimized" if (cfg.attn_chunk or cfg.mla_absorb
-                                    or cfg.seq_parallel or cfg.zero1)
+        "variant": ("optimized" if (cfg.attn_chunk or cfg.seq_parallel
+                                    or cfg.zero1)
                     else "baseline"),
         "mesh": "2x16x16" if multi_pod else "16x16",
         "chips": chips,

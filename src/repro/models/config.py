@@ -74,7 +74,6 @@ class ModelConfig:
     remat: bool = True     # activation-checkpoint each layer in train
     # --- §Perf beyond-paper optimization knobs (baseline = all off) ---
     attn_chunk: int = 0    # >0: online-softmax blocked attention (no SxS)
-    mla_absorb: bool = False   # MLA decode: absorbed-matmul attention
     seq_parallel: bool = False  # sequence-parallel residuals (Megatron-SP)
     zero1: bool = False    # shard optimizer state over the data axis
     pin_cache_sharding: bool = False  # stop decode-cache reshard flapping

@@ -824,9 +824,8 @@ class Model:
 
     def _mla_decode_block(self, h, lp, latent_cache, pos, positions,
                           valid=None, experts=None):
-        """MLA decode: append this token's latent, expand K/V from the
-        latent cache (naive materialization — see §Perf for the absorbed
-        variant)."""
+        """MLA decode: append this token's latent and attend against the
+        latent cache in the absorbed form (`_mla_absorbed_attention`)."""
         cfg = self.cfg
         hn = rms_norm(h, lp["ln1"], cfg.norm_eps)
         q = self._mla_q(hn, lp["attn"], positions)
@@ -839,13 +838,8 @@ class Model:
             latent_cache = latent_cache.at[
                 jnp.arange(latent_cache.shape[0]), pos].set(lat_new[:, 0])
         latent_cache = self._pin_cache(latent_cache, kind="latent")
-        scale = cfg.attn_scale
-        if cfg.mla_absorb:
-            o = self._mla_absorbed_attention(q, latent_cache, lp["attn"],
-                                             pos, scale)
-        else:
-            k, v = self._mla_kv_from_latent(latent_cache, lp["attn"])
-            o = attn.decode_attention(q, k, v, pos + 1, scale=scale)
+        o = self._mla_absorbed_attention(q, latent_cache, lp["attn"], pos,
+                                         cfg.attn_scale)
         o = o.reshape(*o.shape[:2], -1)
         h = h + jnp.einsum("bse,ed->bsd", o, lp["attn"]["wo"])
         h, counts = self._mlp(h, lp, dropless=True, valid=valid,
@@ -853,37 +847,41 @@ class Model:
         return h, latent_cache, counts
 
     def _mla_absorbed_attention(self, q, latent_cache, ap, pos, scale):
-        """Absorbed-matmul MLA decode (§Perf): fold k_up into the query
-        and v_up into the output so attention runs directly against the
-        compressed latent cache — never materializing per-head K/V of
-        shape (B, S, H, d). FLOPs per token drop from
-        O(S·kv_lora·H·(nope+v)) to O(S·H·(kv_lora+rope)), and the
-        (B,S,H,64)x2 temporaries disappear."""
+        """Decode attention against the compressed latent cache
+        (DeepSeek-V2 §2.1): k_up is folded into the query and v_up into
+        the output, so per-head K and V of shape (B, S, H, d) are never
+        built. One query per row makes expanding the cache cost
+        O(S·kv_lora·H·(nope+v)) a token, against O(S·H·(kv_lora+rope))
+        here.
+
+        Both contractions read the cache as stored: the scores contract
+        the query side, [q_nope·k_up, q_rope], with the whole latent row
+        [c_kv, k_rope], and the output weighs its first kv_lora_rank
+        columns. The query side and the probabilities are rounded to
+        the cache's dtype, as the materialised K and V and the
+        probabilities of `attention.attention` are; products accumulate
+        and the softmax runs in float32."""
         cfg = self.cfg
-        b, s_max, _ = latent_cache.shape
-        hn_heads = cfg.num_heads
+        r, heads = cfg.kv_lora_rank, cfg.num_heads
+        dt, f32 = latent_cache.dtype, jnp.float32
         q_nope, q_rope = jnp.split(q[:, 0], [cfg.qk_nope_dim], axis=-1)
-        k_up = ap["k_up"].reshape(cfg.kv_lora_rank, hn_heads,
-                                  cfg.qk_nope_dim)
+        k_up = ap["k_up"].reshape(r, heads, cfg.qk_nope_dim)
         # q_eff[b,h,r] = sum_d q_nope[b,h,d] * k_up[r,h,d]
-        q_eff = jnp.einsum("bhd,rhd->bhr", q_nope.astype(jnp.float32),
-                           k_up.astype(jnp.float32))
-        c_kv, k_rope = jnp.split(latent_cache, [cfg.kv_lora_rank], axis=-1)
-        scores = (jnp.einsum("bhr,bsr->bhs", q_eff,
-                             c_kv.astype(jnp.float32))
-                  + jnp.einsum("bhd,bsd->bhs",
-                               q_rope.astype(jnp.float32),
-                               k_rope.astype(jnp.float32))) * scale
-        idx = jnp.arange(s_max)[None, None, :]
+        q_eff = jnp.einsum("bhd,rhd->bhr", q_nope, k_up,
+                           preferred_element_type=f32)
+        q_lat = jnp.concatenate([q_eff.astype(dt), q_rope.astype(dt)],
+                                axis=-1)
+        scores = jnp.einsum("bhr,bsr->bhs", q_lat, latent_cache,
+                            preferred_element_type=f32) * scale
+        idx = jnp.arange(latent_cache.shape[1])[None, None, :]
         p = pos if pos.ndim == 0 else pos[:, None, None]
-        scores = jnp.where(idx < p + 1, scores, attn.NEG_INF)
-        probs = jax.nn.softmax(scores, axis=-1)
-        out_lat = jnp.einsum("bhs,bsr->bhr", probs,
-                             c_kv.astype(jnp.float32))
-        v_up = ap["v_up"].reshape(cfg.kv_lora_rank, hn_heads,
-                                  cfg.v_head_dim)
-        o = jnp.einsum("bhr,rhd->bhd", out_lat,
-                       v_up.astype(jnp.float32))
+        scores = jnp.where(idx <= p, scores, attn.NEG_INF)
+        probs = jax.nn.softmax(scores, axis=-1).astype(dt)
+        out_lat = jnp.einsum("bhs,bsr->bhr", probs, latent_cache[..., :r],
+                             preferred_element_type=f32)
+        v_up = ap["v_up"].reshape(r, heads, cfg.v_head_dim)
+        o = jnp.einsum("bhr,rhd->bhd", out_lat.astype(dt), v_up,
+                       preferred_element_type=f32)
         return o[:, None].astype(q.dtype)
 
     def _decode_ssm(self, params, cache, token):

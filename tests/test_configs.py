@@ -63,7 +63,7 @@ def test_family_specifics():
     assert (ds.yarn_factor, ds.yarn_original_max_pos, ds.yarn_beta_fast,
             ds.yarn_beta_slow, ds.yarn_mscale, ds.yarn_mscale_all_dim) == \
         (40.0, 4096, 32.0, 1.0, 0.707, 0.707)
-    assert ds.norm_eps == 1e-6 and ds.mla_absorb
+    assert ds.norm_eps == 1e-6
 
 
 @pytest.mark.parametrize("arch", list(ASSIGNED))

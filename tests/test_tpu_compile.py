@@ -45,6 +45,16 @@ def _on(sharding, tree):
         tree)
 
 
+def _decode_args(model, params, slots, max_len):
+    """Shapes of the serving engine's decode call: params, a cache of
+    `slots` rows x `max_len` with a per-row `pos`, one token a row."""
+    cache = jax.eval_shape(
+        lambda p, t: model.prefill(p, t, None, max_len=max_len)[1],
+        params, jax.ShapeDtypeStruct((slots, 1), jnp.int32))
+    cache["pos"] = jax.ShapeDtypeStruct((slots,), jnp.int32)
+    return params, cache, jax.ShapeDtypeStruct((slots, 1), jnp.int32)
+
+
 def _device_bytes(compiled) -> int:
     m = compiled.memory_analysis()
     return (m.argument_size_in_bytes + m.output_size_in_bytes
@@ -99,17 +109,35 @@ def test_minicpm3_4b_serving_step_fits_one_chip(one_chip, phase):
         args = (jax.ShapeDtypeStruct((), jax.random.key(0).dtype),)
         fn = jax.jit(model.init)
     elif phase == "decode":
-        cache = jax.eval_shape(
-            lambda p, t: model.prefill(p, t, None, max_len=2048)[1],
-            params, jax.ShapeDtypeStruct((8, 1), jnp.int32))
-        cache["pos"] = jax.ShapeDtypeStruct((8,), jnp.int32)
-        args = (params, cache, jax.ShapeDtypeStruct((8, 1), jnp.int32))
+        args = _decode_args(model, params, 8, 2048)
         fn = jax.jit(model.decode_step)
     else:
         args = (params, jax.ShapeDtypeStruct((1, 1024), jnp.int32))
         fn = jax.jit(lambda p, t: model.prefill(p, t, None, max_len=2048))
     compiled = fn.lower(*_on(one_chip, args)).compile()
     assert _device_bytes(compiled) < V5E_HBM_BYTES
+
+
+# Temporaries of the decode step at the benchmark cell's 16 slots x
+# 4,096: 408,064 bytes read when decode attends in the latent space;
+# 503,800,320 when it expanded per-head K and V (16 x 4,096 x 40 x 64 in
+# bf16 is 335 MB a tensor). The bound also fails a float32 copy of one
+# layer's latent cache (75.5 MB), were XLA to build one rather than fuse
+# the cast into the contraction, as it does.
+MINICPM3_DECODE_TEMP_BOUND = 32 * 2**20
+
+
+def test_minicpm3_4b_decode_attends_in_latent_space(one_chip):
+    """minicpm3-4b decode at the cell's shape builds no per-head K or V
+    from the latent cache: its temporaries stay under the bound."""
+    from repro.configs import get_config
+    from repro.models import Model
+
+    model = Model(get_config("minicpm3-4b"))
+    args = _decode_args(model, model.abstract_params(), 16, 4096)
+    compiled = jax.jit(model.decode_step).lower(*_on(one_chip, args)).compile()
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < MINICPM3_DECODE_TEMP_BOUND, temp
 
 
 @pytest.mark.parametrize("phase", ["decode", "prefill"])
@@ -127,11 +155,7 @@ def test_deepseek_v2_lite_serving_step_fits_one_chip(one_chip, phase,
     model = Model(get_config("deepseek-v2-lite"))
     params = model.abstract_params()
     if phase == "decode":
-        cache = jax.eval_shape(
-            lambda p, t: model.prefill(p, t, None, max_len=4096)[1],
-            params, jax.ShapeDtypeStruct((16, 1), jnp.int32))
-        cache["pos"] = jax.ShapeDtypeStruct((16,), jnp.int32)
-        args = (params, cache, jax.ShapeDtypeStruct((16, 1), jnp.int32),
+        args = (*_decode_args(model, params, 16, 4096),
                 jax.ShapeDtypeStruct((16,), jnp.bool_))
         fn = jax.jit(model.decode_step)
     else:
