@@ -35,20 +35,22 @@ Model (per micro step of ``dt_s`` seconds, all quantities fluid):
   Algorithm 2's reaction function gates most-aged / wakes least-aged
   cores per machine via vectorized rank selection.
 
-Two backends share the same functional step:
+One macro step (``_macro_step``, written against an array namespace
+``xp``) and one state layout serve two drivers, which advance in chunks
+of macro steps between checkpoints:
 
-* ``backend="numpy"`` — float64, deterministic, and the reference for
-  checkpoint/resume exactness (a resumed run reproduces the
-  uninterrupted run's ``ExperimentResult`` scalars bit-for-bit).
-* ``backend="jax"`` — the step is compiled with ``jax.lax.scan`` over
-  macro periods (an inner scan covers the micro steps); the aging
+* ``backend="numpy"`` — float64, from a Python loop; deterministic, and
+  the reference for checkpoint/resume exactness (a resumed run
+  reproduces the uninterrupted run's ``ExperimentResult`` scalars
+  bit-for-bit).
+* ``backend="jax"`` — float32, as the body of the jitted ``lax.scan``
+  ``fleet_scan`` (an inner scan covers the micro steps); the aging
   settlement routes through ``repro.kernels.aging_update`` — the
   compiled Pallas kernel on TPU, its jnp oracle elsewhere (the kernel in
   interpret mode when ``use_kernel=True`` asks for it off-TPU). The
   engine resolves this once (``FleetEngine.use_kernel`` /
   ``.interpret``) and reports it in the telemetry ``engine`` event.
-  float32: fast, NOT bit-exact vs numpy (documented caveat; see
-  ``--help`` epilogs).
+  Fast, NOT bit-exact vs numpy.
 
 ``backend="auto"`` is the jax backend (jax is a hard dependency).
 
@@ -62,6 +64,7 @@ the event engine on small configs is pinned with tolerances in
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any
 
 import numpy as np
@@ -117,6 +120,14 @@ _KV_S_PER_TOKEN = KV_BYTES_PER_TOKEN / IB_LINK_BW_BPS
 
 _IDLE_BINS = 512          # linear histogram over [-1, 1] for idle_norm
 _EPS = 1e-12
+# Rows of the stacked window sums: `mw` (metrics windows) sums the
+# micro-step observables of `_micro_step` by these names ("cnt" counts
+# the steps); `res` (residency windows) sums per-machine core-seconds.
+_MW_ROWS = ("cnt", "wait", "iter", "cpuw", "sp", "st", "comps")
+_RES_ROWS = ("busy", "idle", "gated", "fbusy")
+# the fluid queue columns, in `_micro_step`'s queue-tuple order
+_QUEUE_KEYS = ("pq_s", "pq_n", "pq_out", "d_batch", "d_tokens", "d_pend",
+               "d_pend_tok", "cpu_backlog")
 
 
 def _float_columns(requests: RequestColumns):
@@ -192,14 +203,11 @@ def _initial_state(shape: _Shape) -> dict[str, np.ndarray]:
         "cpu_backlog": np.zeros(M),
         "busy_s": np.zeros((M, N)),     # busy core-seconds since settle
         "u_last": np.zeros(M), "ov_last": np.zeros(M),
-        # metrics windows (streaming aggregates — bounded for any horizon)
-        "mw_cnt": np.zeros(W), "mw_wait": np.zeros(W),
-        "mw_iter": np.zeros(W), "mw_cpuw": np.zeros(W),
-        "mw_sp": np.zeros(W), "mw_st": np.zeros(W),
-        "mw_comps": np.zeros(W),
-        # residency windows (per machine, for the power models)
-        "res_busy": np.zeros((M, PW)), "res_idle": np.zeros((M, PW)),
-        "res_gated": np.zeros((M, PW)), "res_fbusy": np.zeros((M, PW)),
+        # metrics windows (streaming aggregates — bounded for any
+        # horizon) and per-machine residency windows (for the power
+        # models), rows named by _MW_ROWS / _RES_ROWS
+        "mw": np.zeros((len(_MW_ROWS), W)),
+        "res": np.zeros((len(_RES_ROWS), M, PW)),
         # sample statistics
         "idle_hist": np.zeros(_IDLE_BINS, dtype=np.int64),
         "task_sum": np.zeros(()), "task_cnt": np.zeros(()),
@@ -296,24 +304,24 @@ def _micro_step(xp, shape: _Shape, dyn, q, arr_row):
           cpu_backlog)
     obs = {
         "u": u, "ov": ov, "done": done,
-        "wait_p": wait_p,
-        "iter_mean": xp.mean(iter_period),
-        "cpu_wait": cpu_wait,
+        # the window means, by their _MW_ROWS names
+        "wait": wait_p,
+        "iter": xp.mean(iter_period),
+        "cpuw": cpu_wait,
+        "sp": xp.mean(sp), "st": xp.mean(st),
         "comps": comps_total,
-        "sp_mean": xp.mean(sp), "st_mean": xp.mean(st),
     }
     return q2, obs
 
 
-def _settle_aging(shape: _Shape, dvth, gated, busy_s, advance):
+def _settle_aging(xp, shape: _Shape, dvth, gated, busy_s, advance):
     """Settle one idling period of aging: every non-gated core spends
     its accumulated busy core-seconds at active-allocated stress and the
     remainder of the period at active-unallocated stress; gated cores
     are frozen (ADF = 0). Exact per regime — the NBTI recursion composes
     under a constant ADF."""
     period = shape.steps_per_period * shape.dt_s
-    tau_busy = np.minimum(busy_s, period) if isinstance(busy_s, np.ndarray) \
-        else busy_s
+    tau_busy = xp.minimum(busy_s, period)
     tau_idle = period - tau_busy
     dvth = advance(dvth, gated, tau_busy,
                    temperature.TEMP_ACTIVE_ALLOCATED_C)
@@ -322,13 +330,10 @@ def _settle_aging(shape: _Shape, dvth, gated, busy_s, advance):
     return dvth
 
 
-def _gate_correction(xp, shape: _Shape, active_n, u, ov, g_now, carbon,
-                     n_vec=None):
+def _gate_correction(xp, N, active_n, u, ov, g_now, carbon):
     """Vectorized Algorithm 2 reaction (`idling.core_correction`), with
-    the optional carbon-aware temporal reshaping. `n_vec` (ragged
-    fleets) supplies per-machine core counts; None keeps the uniform
-    scalar `shape.num_cores`."""
-    N = shape.num_cores if n_vec is None else n_vec
+    the optional carbon-aware temporal reshaping. `N` is the core count,
+    per machine (M,) on ragged fleets."""
     tasks = xp.minimum(N * 1.0, u + ov)
     e = (active_n - tasks) / N
     f = xp.where(e >= 0.0, xp.tan(0.785 * e), xp.arctan(1.55 * e))
@@ -460,37 +465,25 @@ class _FleetFaults:
                 for mid in range(M)]
         if self.kind == "guardband":
             self.guard = (model.margin, model.hazard_per_s)
-            if shape.core_counts is None:
-                self.max_failed_n = float(int(model.max_failed_frac * N))
-                self.thresh = np.stack([r.exponential(1.0, size=N)
-                                        for r in rngs])
-            else:
-                # per-machine failure budgets; padded lanes get an
-                # infinite threshold so they can never fail
-                self.max_failed_n = np.array(
-                    [float(int(model.max_failed_frac * n))
-                     for n in counts])
-                self.thresh = np.full((M, N), np.inf)
-                for mid, (r, n) in enumerate(zip(rngs, counts)):
-                    self.thresh[mid, :n] = r.exponential(1.0, size=n)
+            # per-machine failure budgets; the padded lanes of a ragged
+            # fleet get an infinite threshold so they can never fail
+            self.max_failed_n = np.array(
+                [float(int(model.max_failed_frac * n)) for n in counts])
+            self.thresh = np.full((M, N), np.inf)
+            for mid, (r, n) in enumerate(zip(rngs, counts)):
+                self.thresh[mid, :n] = r.exponential(1.0, size=n)
         elif self.kind == "machine-crash":
             for mid, rng in enumerate(rngs):
                 t = float(rng.exponential(model.mttf_s))
                 while t < dur:
                     self.n_crashes += 1
                     down_until = t + model.reboot_s
-                    k0 = min(int(t / self.period), shape.n_macro - 1)
-                    self.onset[k0, mid] = True
-                    k1 = min(int(min(down_until, dur) / self.period),
-                             shape.n_macro - 1)
-                    for k in range(k0, k1 + 1):
-                        lo = max(t, k * self.period)
-                        hi = min(down_until, (k + 1) * self.period, dur)
-                        if hi > lo:
-                            self.up_frac[k, mid] -= (hi - lo) / self.period
-                    self.static_lost_core_s += counts[mid] \
-                        * (min(down_until, dur) - t)
-                    self.windows.append((t, min(down_until, dur)))
+                    t1 = min(down_until, dur)
+                    self.onset[min(int(t / self.period),
+                                   shape.n_macro - 1), mid] = True
+                    self._subtract_overlap(self.up_frac, mid, t, t1)
+                    self.static_lost_core_s += counts[mid] * (t1 - t)
+                    self.windows.append((t, t1))
                     t = down_until + float(rng.exponential(model.mttf_s))
         else:   # transient-stall
             p = -np.expm1(-model.rate_per_s * self.period)
@@ -504,15 +497,20 @@ class _FleetFaults:
                     self.n_stalls += 1
                     t0 = (k + 1) * self.period
                     t1 = min(t0 + model.stall_s, dur)
-                    k1 = min(int(t1 / self.period), shape.n_macro - 1)
-                    for kk in range(k + 1, k1 + 1):
-                        lo = max(t0, kk * self.period)
-                        hi = min(t1, (kk + 1) * self.period)
-                        if hi > lo:
-                            self.cap_mult[kk, mid] -= \
-                                (hi - lo) / self.period * slow_loss
+                    self._subtract_overlap(self.cap_mult, mid, t0, t1,
+                                           slow_loss)
                     if t1 > t0:
                         self.windows.append((t0, t1))
+
+    def _subtract_overlap(self, col, mid, t0, t1, weight=1.0):
+        """Subtract from `col[:, mid]` the share of each macro period
+        that [t0, t1) covers, times `weight`."""
+        last = col.shape[0] - 1
+        for k in range(min(int(t0 / self.period), last),
+                       min(int(t1 / self.period), last) + 1):
+            lo, hi = max(t0, k * self.period), min(t1, (k + 1) * self.period)
+            if hi > lo:
+                col[k, mid] -= (hi - lo) / self.period * weight
 
     def robustness(self, state, completed: int, submitted: int) -> dict:
         """Fleet-side robustness scalars (same keys the event engine's
@@ -543,17 +541,177 @@ class _FleetFaults:
         }
 
 
-def _derived(xp, shape: _Shape, f0, dvth, gated, headroom):
-    """Per-period derived quantities: settled per-core speeds and the
-    per-machine active-core mean speed used by the fluid layers."""
+def _derived(xp, f0, dvth, gated, headroom):
+    """Per-period derived quantities: settled per-core speeds `f`, the
+    per-machine active-core mean speed `sm` used by the fluid layers and
+    the active-core count `active_n` (in the speeds' dtype)."""
     f = f0 * (1.0 - dvth / headroom)
     active = ~gated
-    active_n = xp.sum(active, axis=1)
+    active_n = xp.sum(active, axis=1).astype(f.dtype)
     sm = xp.sum(xp.where(active, f, 0.0), axis=1) / xp.maximum(
         active_n, 1.0)
-    sp = sm[:shape.n_prompt]
-    st = sm[shape.n_prompt:]
-    return f, sp, st, sm, active_n
+    return f, sm, active_n
+
+
+@dataclasses.dataclass(frozen=True)
+class _Run:
+    """What `_macro_step` reads besides state and inputs: the run's
+    constants in the backend's dtypes, and the backend's own pieces."""
+    xp: Any               # numpy or jax.numpy
+    int: Any              # index dtype: int64 on numpy, int32 on jax
+    scan: Any             # scan(body, carry, xs) -> (carry, ys), as lax.scan
+    add_at: Any           # add_at(a, idx, v): `a` with `v` added at a[idx]
+    advance: Any          # advance(dvth, gated, tau, temp_c) -> dvth'
+    shape: _Shape
+    f0: Any               # (M, N) initial per-core frequencies
+    headroom: float
+    pad: Any              # (M, N) padded lanes of a ragged fleet, or None
+    n_vec: Any            # (M,) core counts of a ragged fleet, or the count
+    carbon: Any           # carbon-aware gating parameters, or None
+    faults: Any           # the run's _FleetFaults, or None
+    thresh: Any           # (M, N) guardband failure thresholds, or None
+
+
+def _np_scan(body, carry, xs):
+    """`jax.lax.scan` for the numpy backend: a Python loop over the
+    leading axis of the tuple `xs`, stacking the per-step outputs."""
+    ys = []
+    for i in range(len(xs[0])):
+        carry, y = body(carry, tuple(x[i] for x in xs))
+        ys.append(y)
+    if ys[0] is None:
+        return carry, None
+    return carry, tuple(np.stack(y) for y in zip(*ys))
+
+
+def _np_add_at(a, idx, v):
+    np.add.at(a, idx, v)
+    return a
+
+
+def _to_jax(v):
+    """A numpy array for the jax backend: bool stays bool, integers
+    become int32 and everything else float32."""
+    import jax.numpy as jnp
+    v = np.asarray(v)
+    if v.dtype == bool:
+        return jnp.asarray(v)
+    return jnp.asarray(v, jnp.int32 if v.dtype.kind in "iu"
+                       else jnp.float32)
+
+
+def _macro_step(run: _Run, st: dict, xs):
+    """A scan body that advances the fleet one macro (idling) period:
+    the micro steps with their window, residency and busy-time sums,
+    then, in the event engine's periodic-tick order, aging settlement,
+    guardband failures, the idle histogram and gating. `xs` is one row
+    of `FleetEngine._inputs`."""
+    xp, sh, fx = run.xp, run.shape, run.faults
+    pad, n_vec = run.pad, run.n_vec
+    guard_on = fx is not None and fx.guard is not None
+    arr_rows, ts, g_now = xs[:3]
+    gated_eff = (st["gated"] | st["failed"]) if guard_on else st["gated"]
+    if pad is not None:
+        gated_eff = gated_eff | pad
+    f, sm, active_n = _derived(xp, run.f0, st["dvth"], gated_eff,
+                               run.headroom)
+    if fx is not None:
+        up_row, onset_row, mult_row = xs[3:]
+        # capacity columns: stalls scale speed, crashes scale the live
+        # core count
+        sm = sm * mult_row
+        active_n = active_n * up_row
+        # a machine with no live cores has zero capacity (via active_n)
+        # but must keep a finite nominal speed for the 1/speed
+        # bookkeeping terms
+        f_all = xp.mean(f, axis=1) if pad is None \
+            else xp.sum(f * ~pad, axis=1) / n_vec
+        sm = xp.where(active_n > 0, sm, f_all)
+    dyn = (sm[:sh.n_prompt], sm[sh.n_prompt:], sm, active_n)
+    q = tuple(st[k] for k in _QUEUE_KEYS)
+    if fx is not None:
+        q, retried = _redistribute_queues(xp, q, onset_row, up_row,
+                                          sh.n_prompt)
+
+    def micro(carry, x):
+        q, acc, dyn, gated = carry
+        arr_row, t = x
+        q, obs = _micro_step(xp, sh, dyn, q, arr_row)
+        sm, active_n = dyn[2:]
+        u, ov, done = obs["u"], obs["ov"], obs["done"]
+        # streaming window sums (bounded memory for any horizon)
+        w = xp.minimum((t / sh.mwin_s).astype(run.int), sh.n_mwin - 1)
+        pw = xp.minimum((t / sh.pwin_s).astype(run.int), sh.n_pwin - 1)
+        busy_cs = done / sm
+        tasks = u + ov
+        acc["mw"] = run.add_at(acc["mw"], (slice(None), w), xp.stack(
+            [1.0] + [obs[r] for r in _MW_ROWS[1:]]))
+        acc["res"] = run.add_at(acc["res"], (slice(None), slice(None), pw),
+                                xp.stack([
+                                    busy_cs, active_n * sh.dt_s - busy_cs,
+                                    (n_vec - active_n) * sh.dt_s, done],
+                                    axis=0))
+        acc["task_sum"] = acc["task_sum"] + tasks.sum()
+        acc["task_cnt"] = acc["task_cnt"] + tasks.size
+        acc["task_max"] = xp.maximum(acc["task_max"], tasks.max())
+        acc["completions"] = acc["completions"] + obs["comps"]
+        # spread busy time evenly over this period's active set
+        acc["busy_s"] = acc["busy_s"] + xp.where(
+            gated, 0.0, (busy_cs / xp.maximum(active_n, 1.0))[:, None])
+        return (q, acc, dyn, gated), (u, ov)
+
+    acc = {k: st[k] for k in ("mw", "res", "task_sum", "task_cnt",
+                              "task_max", "completions", "busy_s")}
+    (q, acc, _, _), (us, ovs) = run.scan(
+        micro, (q, acc, dyn, gated_eff), (arr_rows, ts))
+    u, ov = us[-1], ovs[-1]
+    dvth = _settle_aging(xp, sh, st["dvth"], gated_eff, acc["busy_s"],
+                         run.advance)
+    failed = st.get("failed")
+    if guard_on:
+        margin, hazard = fx.guard
+        over = (dvth / run.headroom - margin) / margin
+        haz = hazard * fx.period * xp.maximum(over, 0.0)
+        cum = st["cum_haz"] + xp.where(failed, 0.0, haz)
+        cand = (cum >= run.thresh) & ~failed
+        allowed = xp.maximum(
+            fx.max_failed_n
+            - xp.sum(failed, axis=1).astype(dvth.dtype), 0.0)
+        key = xp.where(cand, cum - run.thresh, -np.inf)
+        rank = xp.argsort(xp.argsort(-key, axis=1), axis=1)
+        failed = failed | (cand & (rank < allowed[:, None]))
+    idle_norm = (active_n - u - ov) / n_vec
+    bins = xp.clip(((idle_norm + 1.0) * 0.5
+                    * (_IDLE_BINS - 1)).astype(run.int), 0, _IDLE_BINS - 1)
+    idle_hist = run.add_at(st["idle_hist"], bins, 1)
+    gated = st["gated"]
+    if sh.gating:
+        corr = _gate_correction(xp, n_vec, active_n, u, ov, g_now,
+                                run.carbon)
+        # padded lanes behave like permanently failed cores: never
+        # gateable, never wakeable
+        fail_eff = failed
+        if pad is not None:
+            fail_eff = pad if fail_eff is None else fail_eff | pad
+        gated = _apply_gating(xp, corr, gated,
+                              xp.ceil(xp.minimum(u, active_n)), dvth,
+                              failed=fail_eff)
+    st = dict(st)
+    st.update(acc)
+    st.update(zip(_QUEUE_KEYS, q))
+    st["busy_s"] = xp.zeros_like(acc["busy_s"])
+    st["dvth"] = dvth
+    st["gated"] = gated
+    st["idle_hist"] = idle_hist
+    st["u_last"], st["ov_last"] = u, ov
+    if fx is not None:
+        st["retried"] = st["retried"] + retried
+        if guard_on:
+            st["failed"] = failed
+            st["cum_haz"] = cum
+            st["lost_core_s"] = (st["lost_core_s"] + xp.sum(failed)
+                                 .astype(dvth.dtype) * fx.period)
+    return st, None
 
 
 # ---------------------------------------------------------------------- #
@@ -745,9 +903,7 @@ class FleetEngine:
     # -- checkpoint/resume --------------------------------------------- #
     def _checkpoint(self, macro: int) -> None:
         from repro.checkpoint import store
-        state = {k: np.asarray(v) for k, v in self.state.items()}
-        state["macro"] = np.asarray(macro, dtype=np.int64)
-        store.save(self.checkpoint_dir, macro, state,
+        store.save(self.checkpoint_dir, macro, self.state,
                    extra={"config": self.cfg.fingerprint(),
                           "engine": "fleet", "backend": self.backend,
                           "macro": macro})
@@ -775,148 +931,80 @@ class FleetEngine:
         self.resumed_from = step
         return int(self.state["macro"])
 
-    # -- numpy driver --------------------------------------------------- #
+    # -- drivers -------------------------------------------------------- #
+    def _inputs(self, arr: np.ndarray) -> tuple:
+        """Every macro step's inputs (float64) along the leading axis:
+        the micro steps' binned arrivals and start times, the carbon
+        intensity at the period's end, and any fault columns."""
+        sh = self.shape
+        spp = sh.steps_per_period
+        ts = np.arange(sh.n_macro * spp) * sh.dt_s
+        if self._carbon_gate:
+            t_end = (np.arange(sh.n_macro) + 1) * spp * sh.dt_s
+            g = np.array([self._carbon_gate[0].g_per_kwh(t) for t in t_end])
+        else:
+            g = np.zeros(sh.n_macro)
+        xs = (arr.reshape(sh.n_macro, spp, 3), ts.reshape(sh.n_macro, spp),
+              g)
+        fx = self._faults
+        if fx is not None:
+            xs += (fx.up_frac, fx.onset, fx.cap_mult)
+        return xs
+
+    def _step_run(self, xp, cast, **ops) -> _Run:
+        """This run's `_Run` for `xp`, its constant arrays through
+        `cast`."""
+        fx = self._faults
+        guard_on = fx is not None and fx.guard is not None
+        return _Run(
+            xp=xp, shape=self.shape, f0=cast(self.f0),
+            headroom=self.params.headroom,
+            pad=None if self._pad is None else cast(self._pad),
+            n_vec=(self.shape.num_cores if self._pad is None
+                   else cast(self._n_vec)),
+            carbon=self._carbon_gate[1] if self._carbon_gate else None,
+            faults=fx, thresh=cast(fx.thresh) if guard_on else None, **ops)
+
+    def _drive(self, advance, carry: dict, xs: tuple,
+               start_macro: int) -> None:
+        """Advance macro steps `start_macro..n_macro` in chunks that end
+        at each checkpoint; `advance(carry, xs_chunk)` returns the carry
+        after the chunk, which `_unpack` writes back to `self.state`."""
+        sh = self.shape
+        per = max(1, int(round(self.checkpoint_every_s
+                               / self.cfg.idling_period_s)))
+        k = start_macro
+        while k < sh.n_macro:
+            k2 = min((k // per + 1) * per, sh.n_macro) \
+                if self.checkpoint_dir else sh.n_macro
+            carry = advance(carry, tuple(x[k:k2] for x in xs))
+            k = k2
+            with span("fleetsim.unpack"):
+                self._unpack(carry, k)
+            if self.checkpoint_dir and k < sh.n_macro:
+                self._checkpoint(k)
+
+    def _unpack(self, carry: dict, macro: int) -> None:
+        """Write a driver's carry back to `self.state`, each array in
+        its own numpy dtype."""
+        s = self.state
+        for key, v in carry.items():
+            s[key] = np.asarray(v, dtype=s[key].dtype)
+        s["macro"] = np.asarray(macro, dtype=np.int64)
+
     def _advance_numpy(self, dvth, gated, tau, temp_c):
         a = aging.adf(self.params, temp_c, 1.0)
         tau = np.where(gated, 0.0, np.broadcast_to(tau, dvth.shape))
         return aging.advance_dvth(self.params, dvth, a, tau)
 
     def _run_numpy(self, arr: np.ndarray, start_macro: int) -> None:
-        sh, st = self.shape, self.state
-        xp = np
-        P = sh.n_prompt
-        spp = sh.steps_per_period
-        next_ckpt = self._next_ckpt(start_macro)
-        g_fn = self._carbon_gate[0].g_per_kwh if self._carbon_gate else None
-        fx = self._faults
-        pad = self._pad
-        # per-machine core counts: the scalar num_cores on uniform
-        # fleets (identical arithmetic to the pre-hardware engine), a
-        # (M,) vector on ragged ones
-        n_vec = sh.num_cores if pad is None else self._n_vec
-        for k in range(start_macro, sh.n_macro):
-            gated_eff = st["gated"] if pad is None else st["gated"] | pad
-            if fx is not None:
-                if "failed" in st:
-                    gated_eff = gated_eff | st["failed"]
-                if fx.onset[k].any():
-                    q0 = (st["pq_s"], st["pq_n"], st["pq_out"],
-                          st["d_batch"], st["d_tokens"], st["d_pend"],
-                          st["d_pend_tok"], st["cpu_backlog"])
-                    q0, retried = _redistribute_queues(
-                        xp, q0, fx.onset[k], fx.up_frac[k], P)
-                    (st["pq_s"], st["pq_n"], st["pq_out"], st["d_batch"],
-                     st["d_tokens"], st["d_pend"], st["d_pend_tok"],
-                     st["cpu_backlog"]) = q0
-                    st["retried"] = st["retried"] + retried
-            f, sp, spd_t, sm, active_n = _derived(
-                xp, sh, self.f0, st["dvth"], gated_eff,
-                self.params.headroom)
-            if fx is not None:
-                # capacity columns: stalls scale speed, crashes scale
-                # the live core count
-                sm = sm * fx.cap_mult[k]
-                active_n = active_n * fx.up_frac[k]
-                # a machine with no live cores has zero capacity (via
-                # active_n) but must keep a finite nominal speed for the
-                # 1/speed bookkeeping terms
-                f_all = f.mean(axis=1) if pad is None \
-                    else (f * ~pad).sum(axis=1) / n_vec
-                sm = xp.where(active_n > 0, sm, f_all)
-                sp, spd_t = sm[:P], sm[P:]
-            dyn = (sp, spd_t, sm, active_n)
-            q = (st["pq_s"], st["pq_n"], st["pq_out"], st["d_batch"],
-                 st["d_tokens"], st["d_pend"], st["d_pend_tok"],
-                 st["cpu_backlog"])
-            for j in range(spp):
-                step = k * spp + j
-                t = step * sh.dt_s
-                q, obs = _micro_step(xp, sh, dyn, q, arr[step])
-                u, ov, done = obs["u"], obs["ov"], obs["done"]
-                # streaming window aggregates (in place: bounded memory)
-                w = min(int(t / sh.mwin_s), sh.n_mwin - 1)
-                st["mw_cnt"][w] += 1.0
-                st["mw_wait"][w] += obs["wait_p"]
-                st["mw_iter"][w] += obs["iter_mean"]
-                st["mw_cpuw"][w] += obs["cpu_wait"]
-                st["mw_sp"][w] += obs["sp_mean"]
-                st["mw_st"][w] += obs["st_mean"]
-                st["mw_comps"][w] += obs["comps"]
-                pw = min(int(t / sh.pwin_s), sh.n_pwin - 1)
-                busy_cs = done / sm
-                st["res_busy"][:, pw] += busy_cs
-                st["res_idle"][:, pw] += active_n * sh.dt_s - busy_cs
-                st["res_gated"][:, pw] += (n_vec - active_n) * sh.dt_s
-                st["res_fbusy"][:, pw] += done
-                tasks = u + ov
-                st["task_sum"] += tasks.sum()
-                st["task_cnt"] += tasks.size
-                st["task_max"] = np.maximum(st["task_max"], tasks.max())
-                st["completions"] += obs["comps"]
-                # spread busy time evenly over this period's active set
-                st["busy_s"] += np.where(
-                    gated_eff, 0.0,
-                    (busy_cs / np.maximum(active_n, 1.0))[:, None])
-            (st["pq_s"], st["pq_n"], st["pq_out"], st["d_batch"],
-             st["d_tokens"], st["d_pend"], st["d_pend_tok"],
-             st["cpu_backlog"]) = q
-            st["u_last"], st["ov_last"] = u, ov
+        run = self._step_run(np, np.asarray, int=np.int64, scan=_np_scan,
+                             add_at=_np_add_at, advance=self._advance_numpy)
+        step = functools.partial(_macro_step, run)
+        state = {k: v for k, v in self.state.items() if k != "macro"}
+        self._drive(lambda st, xs: _np_scan(step, st, xs)[0], state,
+                    self._inputs(arr), start_macro)
 
-            # macro boundary: settle aging, sample, gate (same order as
-            # the event engine's periodic tick).
-            st["dvth"] = _settle_aging(sh, st["dvth"], gated_eff,
-                                       st["busy_s"], self._advance_numpy)
-            st["busy_s"][:] = 0.0
-            if fx is not None and fx.guard is not None:
-                margin, hazard = fx.guard
-                over = (st["dvth"] / self.params.headroom
-                        - margin) / margin
-                haz = hazard * fx.period * np.maximum(over, 0.0)
-                st["cum_haz"] = st["cum_haz"] + np.where(
-                    st["failed"], 0.0, haz)
-                cand = (st["cum_haz"] >= fx.thresh) & ~st["failed"]
-                allowed = np.maximum(
-                    fx.max_failed_n - st["failed"].sum(axis=1), 0.0)
-                key = np.where(cand, st["cum_haz"] - fx.thresh, -np.inf)
-                rank = np.argsort(np.argsort(-key, axis=1), axis=1)
-                st["failed"] = st["failed"] | (
-                    cand & (rank < allowed[:, None]))
-                st["lost_core_s"] = (st["lost_core_s"]
-                                     + st["failed"].sum() * fx.period)
-            idle_norm = (active_n - u - ov) / n_vec
-            bins = np.clip(((idle_norm + 1.0) * 0.5
-                            * (_IDLE_BINS - 1)).astype(np.int64),
-                           0, _IDLE_BINS - 1)
-            st["idle_hist"] += np.bincount(bins, minlength=_IDLE_BINS)
-            if sh.gating:
-                t_now = (k + 1) * spp * sh.dt_s
-                g_now = g_fn(t_now) if g_fn else 0.0
-                carbon = self._carbon_gate[1] if self._carbon_gate else None
-                corr = _gate_correction(xp, sh, active_n, u, ov, g_now,
-                                        carbon, n_vec=self._n_vec)
-                # padded lanes behave like permanently failed cores:
-                # never gateable, never wakeable
-                fail_eff = st.get("failed")
-                if pad is not None:
-                    fail_eff = pad if fail_eff is None \
-                        else fail_eff | pad
-                st["gated"] = _apply_gating(xp, corr, st["gated"],
-                                            np.ceil(np.minimum(u,
-                                                               active_n)),
-                                            st["dvth"],
-                                            failed=fail_eff)
-            st["macro"] = np.asarray(k + 1, dtype=np.int64)
-            if self.checkpoint_dir and k + 1 >= next_ckpt \
-                    and k + 1 < sh.n_macro:
-                self._checkpoint(k + 1)
-                next_ckpt = self._next_ckpt(k + 1)
-
-    def _next_ckpt(self, macro: int) -> int:
-        per = max(1, int(round(self.checkpoint_every_s
-                               / self.cfg.idling_period_s)))
-        return (macro // per + 1) * per
-
-    # -- jax driver ----------------------------------------------------- #
     def _build_jax(self, arr: np.ndarray):
         """The jitted macro scan and its inputs: ``(scan, state, xs)``.
         ``scan(state, xs[k:k2])`` advances macro steps ``k..k2``; ``xs``
@@ -925,28 +1013,8 @@ class FleetEngine:
         import jax.numpy as jnp
         from repro.kernels.aging_update.ops import advance_fleet
 
-        sh = self.shape
         params = self.params
         use_kernel, interpret = self.use_kernel, self.interpret
-        f0 = jnp.asarray(self.f0, jnp.float32)
-        spp = sh.steps_per_period
-        carbon = self._carbon_gate[1] if self._carbon_gate else None
-        # ragged-fleet constants (static Python branches below — the
-        # uniform trace is byte-identical to the pre-hardware engine)
-        pad = None if self._pad is None else jnp.asarray(self._pad)
-        n_vec = (sh.num_cores if pad is None
-                 else jnp.asarray(self._n_vec, jnp.float32))
-        # Fault columns (constants of the run; the guardband threshold
-        # crossing is the only dynamic part and lives in the carry).
-        fx = self._faults
-        guard_on = fx is not None and fx.guard is not None
-        thresh_j = jnp.asarray(fx.thresh, jnp.float32) if guard_on else None
-        if self._carbon_gate:
-            t_macro = (np.arange(sh.n_macro) + 1) * spp * sh.dt_s
-            g_arr = np.array([self._carbon_gate[0].g_per_kwh(t)
-                              for t in t_macro], dtype=np.float32)
-        else:
-            g_arr = np.zeros(sh.n_macro, dtype=np.float32)
 
         def advance(dvth, gated, tau, temp_c):
             flat = dvth.reshape(-1)
@@ -957,219 +1025,49 @@ class FleetEngine:
                                 use_kernel=use_kernel, interpret=interpret)
             return out.reshape(dvth.shape)
 
-        def micro_body(carry, xs):
-            q, acc, dyn, gated = carry
-            arr_row, t = xs
-            q, obs = _micro_step(jnp, sh, dyn, q, arr_row)
-            sp, st_, sm, active_n = dyn
-            u, ov, done = obs["u"], obs["ov"], obs["done"]
-            w = jnp.minimum((t / sh.mwin_s).astype(jnp.int32),
-                            sh.n_mwin - 1)
-            pw = jnp.minimum((t / sh.pwin_s).astype(jnp.int32),
-                             sh.n_pwin - 1)
-            busy_cs = done / sm
-            tasks = u + ov
-            acc = dict(acc)
-            acc["mw"] = acc["mw"].at[:, w].add(jnp.stack([
-                1.0, obs["wait_p"], obs["iter_mean"], obs["cpu_wait"],
-                obs["sp_mean"], obs["st_mean"], obs["comps"]]))
-            acc["res"] = acc["res"].at[:, :, pw].add(jnp.stack([
-                busy_cs, active_n * sh.dt_s - busy_cs,
-                (n_vec - active_n) * sh.dt_s, done], axis=0))
-            acc["task_sum"] = acc["task_sum"] + tasks.sum()
-            acc["task_cnt"] = acc["task_cnt"] + tasks.size
-            acc["task_max"] = jnp.maximum(acc["task_max"], tasks.max())
-            acc["completions"] = acc["completions"] + obs["comps"]
-            acc["busy_s"] = acc["busy_s"] + jnp.where(
-                gated, 0.0, (busy_cs / jnp.maximum(active_n, 1.0))[:, None])
-            return (q, acc, dyn, gated), (u, ov)
-
-        def macro_body(carry, xs):
-            st = carry
-            if fx is not None:
-                arr_rows, ts, g_now, up_row, onset_row, mult_row = xs
-            else:
-                arr_rows, ts, g_now = xs
-            gated_eff = (st["gated"] | st["failed"]) if guard_on \
-                else st["gated"]
-            if pad is not None:
-                gated_eff = gated_eff | pad
-            f = f0 * (1.0 - st["dvth"] / params.headroom)
-            active = ~gated_eff
-            active_n = jnp.sum(active, axis=1).astype(jnp.float32)
-            sm = (jnp.sum(jnp.where(active, f, 0.0), axis=1)
-                  / jnp.maximum(active_n, 1.0))
-            if fx is not None:
-                sm = sm * mult_row
-                active_n = active_n * up_row
-                f_all = jnp.mean(f, axis=1) if pad is None \
-                    else jnp.sum(f * ~pad, axis=1) / n_vec
-                sm = jnp.where(active_n > 0, sm, f_all)
-            dyn = (sm[:sh.n_prompt], sm[sh.n_prompt:], sm, active_n)
-            q = (st["pq_s"], st["pq_n"], st["pq_out"], st["d_batch"],
-                 st["d_tokens"], st["d_pend"], st["d_pend_tok"],
-                 st["cpu_backlog"])
-            if fx is not None:
-                q, retried = _redistribute_queues(jnp, q, onset_row,
-                                                  up_row, sh.n_prompt)
-            acc = {k2: st[k2] for k2 in
-                   ("mw", "res", "task_sum", "task_cnt", "task_max",
-                    "completions", "busy_s")}
-            (q, acc, _, _), (us, ovs) = jax.lax.scan(
-                micro_body, (q, acc, dyn, gated_eff), (arr_rows, ts))
-            u, ov = us[-1], ovs[-1]
-            dvth = _settle_aging(sh, st["dvth"], gated_eff,
-                                 acc["busy_s"], advance)
-            failed = st.get("failed")
-            if guard_on:
-                margin, hazard = fx.guard
-                over = (dvth / params.headroom - margin) / margin
-                haz = hazard * fx.period * jnp.maximum(over, 0.0)
-                cum = st["cum_haz"] + jnp.where(failed, 0.0, haz)
-                cand = (cum >= thresh_j) & ~failed
-                allowed = jnp.maximum(
-                    fx.max_failed_n
-                    - jnp.sum(failed, axis=1).astype(jnp.float32), 0.0)
-                key = jnp.where(cand, cum - thresh_j, -jnp.inf)
-                rank = jnp.argsort(jnp.argsort(-key, axis=1), axis=1)
-                failed = failed | (cand & (rank < allowed[:, None]))
-            idle_norm = (active_n - u - ov) / n_vec
-            bins = jnp.clip(((idle_norm + 1.0) * 0.5
-                             * (_IDLE_BINS - 1)).astype(jnp.int32),
-                            0, _IDLE_BINS - 1)
-            idle_hist = st["idle_hist"].at[bins].add(1)
-            gated = st["gated"]
-            if sh.gating:
-                corr = _gate_correction(jnp, sh, active_n, u, ov, g_now,
-                                        carbon,
-                                        n_vec=None if pad is None
-                                        else n_vec)
-                fail_eff = failed
-                if pad is not None:
-                    fail_eff = pad if fail_eff is None \
-                        else fail_eff | pad
-                gated = _apply_gating(
-                    jnp, corr, gated,
-                    jnp.ceil(jnp.minimum(u, active_n)), dvth,
-                    failed=fail_eff)
-            st = dict(st)
-            st.update(acc)
-            (st["pq_s"], st["pq_n"], st["pq_out"], st["d_batch"],
-             st["d_tokens"], st["d_pend"], st["d_pend_tok"],
-             st["cpu_backlog"]) = q
-            st["busy_s"] = jnp.zeros_like(acc["busy_s"])
-            st["dvth"] = dvth
-            st["gated"] = gated
-            st["idle_hist"] = idle_hist
-            st["u_last"], st["ov_last"] = u, ov
-            if fx is not None:
-                st["retried"] = st["retried"] + retried
-                if guard_on:
-                    st["failed"] = failed
-                    st["cum_haz"] = cum
-                    st["lost_core_s"] = (
-                        st["lost_core_s"]
-                        + jnp.sum(failed).astype(jnp.float32) * fx.period)
-            return st, None
-
-        # pack numpy state -> f32 jax pytree (mw/res stacked for cheap
-        # scatter adds inside the scan)
-        s = self.state
-        jst = {k: jnp.asarray(v, jnp.float32)
-               for k, v in s.items()
-               if k not in ("macro", "idle_hist", "gated", "failed",
-                            "mw_cnt", "mw_wait", "mw_iter", "mw_cpuw",
-                            "mw_sp", "mw_st", "mw_comps", "res_busy",
-                            "res_idle", "res_gated", "res_fbusy")}
-        jst["gated"] = jnp.asarray(s["gated"])
-        if guard_on:
-            jst["failed"] = jnp.asarray(s["failed"])
-        jst["idle_hist"] = jnp.asarray(s["idle_hist"], jnp.int32)
-        jst["mw"] = jnp.asarray(np.stack([
-            s["mw_cnt"], s["mw_wait"], s["mw_iter"], s["mw_cpuw"],
-            s["mw_sp"], s["mw_st"], s["mw_comps"]]), jnp.float32)
-        jst["res"] = jnp.asarray(np.stack([
-            s["res_busy"], s["res_idle"], s["res_gated"],
-            s["res_fbusy"]]), jnp.float32)
-
-        n_steps = sh.n_macro * spp
-        ts = (np.arange(n_steps) * sh.dt_s).astype(np.float32)
-        xs = (jnp.asarray(arr.reshape(sh.n_macro, spp, 3), jnp.float32),
-              jnp.asarray(ts.reshape(sh.n_macro, spp)),
-              jnp.asarray(g_arr))
-        if fx is not None:
-            xs = xs + (jnp.asarray(fx.up_frac, jnp.float32),
-                       jnp.asarray(fx.onset),
-                       jnp.asarray(fx.cap_mult, jnp.float32))
+        run = self._step_run(jnp, _to_jax, int=jnp.int32, scan=jax.lax.scan,
+                             add_at=lambda a, idx, v: a.at[idx].add(v),
+                             advance=advance)
+        step = functools.partial(_macro_step, run)
 
         @jax.jit
         def fleet_scan(st0, xs):
-            return jax.lax.scan(macro_body, st0, xs)
-        return fleet_scan, jst, xs
+            return jax.lax.scan(step, st0, xs)
+        jst = {k: _to_jax(v) for k, v in self.state.items() if k != "macro"}
+        return fleet_scan, jst, tuple(_to_jax(x) for x in self._inputs(arr))
 
     def _run_jax(self, arr: np.ndarray, start_macro: int) -> None:
         import jax
-        sh = self.shape
         with span("fleetsim.build"):
             scan, jst, xs = self._build_jax(arr)
-        per = max(1, int(round(self.checkpoint_every_s
-                               / self.cfg.idling_period_s)))
         compiled = {}       # chunk length -> executable, compiled ahead
-        k = start_macro
-        while k < sh.n_macro:
-            k2 = min(k + per, sh.n_macro) if self.checkpoint_dir \
-                else sh.n_macro
-            chunk = tuple(x[k:k2] for x in xs)
-            step = compiled.get(k2 - k)
+
+        def advance(jst, chunk):
+            n = chunk[0].shape[0]
+            step = compiled.get(n)
             if step is None:
                 with self._compile:
-                    step = compiled[k2 - k] = scan.lower(jst, chunk).compile()
+                    step = compiled[n] = scan.lower(jst, chunk).compile()
             with span("fleetsim.scan"):
                 jst, _ = step(jst, chunk)
                 jax.block_until_ready(jst)
-            k = k2
-            with span("fleetsim.unpack"):
-                self._unpack_jax(jst, k)
-            if self.checkpoint_dir and k < sh.n_macro:
-                self._checkpoint(k)
-
-    def _unpack_jax(self, jst, macro: int) -> None:
-        s = self.state
-        for key in ("dvth", "pq_s", "pq_n", "pq_out", "d_batch",
-                    "d_tokens", "d_pend", "d_pend_tok", "cpu_backlog",
-                    "busy_s", "u_last", "ov_last", "task_sum",
-                    "task_cnt", "task_max", "completions"):
-            s[key] = np.asarray(jst[key], dtype=np.float64)
-        s["gated"] = np.asarray(jst["gated"])
-        s["idle_hist"] = np.asarray(jst["idle_hist"], dtype=np.int64)
-        mw = np.asarray(jst["mw"], dtype=np.float64)
-        (s["mw_cnt"], s["mw_wait"], s["mw_iter"], s["mw_cpuw"],
-         s["mw_sp"], s["mw_st"], s["mw_comps"]) = mw
-        res = np.asarray(jst["res"], dtype=np.float64)
-        (s["res_busy"], s["res_idle"], s["res_gated"],
-         s["res_fbusy"]) = res
-        if self._faults is not None:
-            for key in ("lost_core_s", "retried", "cum_haz"):
-                if key in jst:
-                    s[key] = np.asarray(jst[key], dtype=np.float64)
-            if "failed" in jst:
-                s["failed"] = np.asarray(jst["failed"])
-        s["macro"] = np.asarray(macro, dtype=np.int64)
+            return jst
+        self._drive(advance, jst, xs, start_macro)
 
     # ------------------------------------------------------------------ #
     # collection
     # ------------------------------------------------------------------ #
     def _window_means(self):
-        st = self.state
-        cnt = np.maximum(st["mw_cnt"], 1.0)
-        means = {k: st[k] / cnt
-                 for k in ("mw_wait", "mw_iter", "mw_cpuw", "mw_sp",
-                           "mw_st")}
+        """Per-window means of the micro-step observables, keyed by
+        their _MW_ROWS names."""
+        mw = dict(zip(_MW_ROWS, self.state["mw"]))
+        cnt = np.maximum(mw["cnt"], 1.0)
+        means = {k: mw[k] / cnt for k in ("wait", "iter", "cpuw", "sp", "st")}
         # empty windows fall back to the run-wide mean
-        have = st["mw_cnt"] > 0
+        have = mw["cnt"] > 0
         for k, v in means.items():
             overall = float(v[have].mean()) if have.any() else \
-                (1.0 if k in ("mw_sp", "mw_st") else 0.0)
+                (1.0 if k in ("sp", "st") else 0.0)
             means[k] = np.where(have, v, overall)
         return means
 
@@ -1184,16 +1082,16 @@ class FleetEngine:
         t_arr, n_in, n_out = _float_columns(self._requests)
         w = np.clip((t_arr / sh.mwin_s).astype(np.int64), 0,
                     sh.n_mwin - 1)
-        sp = mw["mw_sp"][w]
-        wait = mw["mw_wait"][w] + mw["mw_cpuw"][w]
+        sp = mw["sp"][w]
+        wait = mw["wait"][w] + mw["cpuw"][w]
         prefill = (TASK_DURATIONS_S["submit_task"] / sp
                    + PREFILL_BASE_S + PREFILL_PER_TOKEN_S * n_in)
         w2 = np.clip(((t_arr + wait + prefill) / sh.mwin_s)
                      .astype(np.int64), 0, sh.n_mwin - 1)
-        itp = mw["mw_iter"][w2]
+        itp = mw["iter"][w2]
         lat = (_LAT_CPU_PROMPT / sp + wait + prefill
                + n_in * _KV_S_PER_TOKEN
-               + _W_TOKEN_ARRIVAL / mw["mw_st"][w2]
+               + _W_TOKEN_ARRIVAL / mw["st"][w2]
                + 0.5 * itp + n_out * itp)
         done = t_arr + lat <= sh.duration_s
         if not done.any():
@@ -1224,21 +1122,22 @@ class FleetEngine:
         return pcts, below
 
     def residencies(self) -> tuple[StateResidency, ...]:
-        sh, st = self.shape, self.state
+        sh = self.shape
+        res = dict(zip(_RES_ROWS, self.state["res"]))
         out = []
         for m in range(sh.n_machines):
             out.append(StateResidency(
                 num_cores=(sh.num_cores if sh.core_counts is None
                            else sh.core_counts[m]),
                 duration_s=sh.duration_s,
-                busy_core_s=float(st["res_busy"][m].sum()),
-                idle_core_s=float(st["res_idle"][m].sum()),
-                gated_core_s=float(st["res_gated"][m].sum()),
-                freq_busy_core_s=float(st["res_fbusy"][m].sum()),
+                busy_core_s=float(res["busy"][m].sum()),
+                idle_core_s=float(res["idle"][m].sum()),
+                gated_core_s=float(res["gated"][m].sum()),
+                freq_busy_core_s=float(res["fbusy"][m].sum()),
                 window_s=sh.pwin_s,
-                window_busy_s=tuple(st["res_busy"][m]),
-                window_idle_s=tuple(st["res_idle"][m]),
-                window_gated_s=tuple(st["res_gated"][m]),
+                window_busy_s=tuple(res["busy"][m]),
+                window_idle_s=tuple(res["idle"][m]),
+                window_gated_s=tuple(res["gated"][m]),
             ))
         return tuple(out)
 
@@ -1298,15 +1197,16 @@ class FleetEngine:
         """Windowed fleet aggregates into the hub's streaming series —
         ring-buffered, so any horizon stays bounded."""
         sh, st = self.shape, self.state
-        mw = self._window_means()
-        have = np.flatnonzero(st["mw_cnt"] > 0)
+        means = self._window_means()
+        mw = dict(zip(_MW_ROWS, st["mw"]))
+        have = np.flatnonzero(mw["cnt"] > 0)
         tl = hub.timeline("fleet/windows", maxlen=max(len(have), 1))
         for i in have:
             t = float(i * sh.mwin_s)
-            tl.record(t, (float(mw["mw_wait"][i]),
-                          float(mw["mw_iter"][i]),
-                          float(mw["mw_cpuw"][i]),
-                          float(st["mw_comps"][i])))
+            tl.record(t, (float(means["wait"][i]),
+                          float(means["iter"][i]),
+                          float(means["cpuw"][i]),
+                          float(mw["comps"][i])))
         hub.set_gauge("fleet/completions", float(st["completions"]))
         hub.set_gauge("fleet/gated_cores_final",
                       float(self.state["gated"].sum()))

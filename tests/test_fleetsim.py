@@ -9,6 +9,7 @@ to the event engine alone (tests/test_perf_bitexact.py).
 """
 from __future__ import annotations
 
+import json
 import os
 import shutil
 
@@ -141,6 +142,62 @@ class TestBackendAgreement:
                     res_np.fleet_yearly_kgco2eq) < 0.05
 
 
+GOLDEN_PATH = os.path.join(os.path.dirname(__file__),
+                           "fleet_macro_step_golden.json")
+_BASE = ExperimentConfig(duration_s=60.0)
+GOLDEN_CFGS = {
+    "uniform": _BASE,
+    "carbon-aware": _BASE.with_policy(
+        "proposed", carbon_aware=True, intensity="diurnal",
+        intensity_opts=(("amplitude", 0.8), ("period_s", 40.0),
+                        ("phase", 0.0))),
+    "ragged": ExperimentConfig(duration_s=60.0, rate_rps=30.0, seed=2,
+                               n_prompt=1, n_token=2,
+                               fleet="xeon-28c:2+epyc-64c:1"),
+    "guardband": _BASE.with_fault_model("guardband", margin=0.012),
+    "machine-crash": _BASE.with_fault_model("machine-crash",
+                                            mttf_s=120.0),
+    "transient-stall": _BASE.with_fault_model("transient-stall",
+                                              rate_per_s=0.1),
+}
+GOLDEN_CASES = ([(name, "numpy") for name in GOLDEN_CFGS]
+                + [("uniform", "jax"), ("guardband", "jax")])
+
+
+def golden_row(case: str, backend: str) -> dict:
+    """The scalar row that `fleet_macro_step_golden.json` pins."""
+    cfg = GOLDEN_CFGS[case].with_engine("fleet", backend=backend)
+    return run_experiment(cfg).scalars()
+
+
+class TestMacroStepGolden:
+    """Scalar rows of six 60-s configurations (uniform, carbon-aware
+    gating, a ragged fleet, each fleet fault model) on the numpy
+    backend and two on the jax backend, pinned to recorded values.
+    numpy (float64) is held to 1e-12 relative, which leaves an ulp of
+    a SIMD transcendental on another CPU; jax (float32) to 1e-5."""
+
+    @pytest.fixture(scope="class")
+    def golden(self):
+        with open(GOLDEN_PATH) as f:
+            return json.load(f)
+
+    @pytest.mark.parametrize("case,backend", GOLDEN_CASES)
+    def test_scalars(self, golden, case, backend):
+        want = golden[f"{case}/{backend}"]
+        got = golden_row(case, backend)
+        assert got.keys() == want.keys()
+        tol = 1e-12 if backend == "numpy" else 1e-5
+        for k, b in want.items():
+            a = got[k]
+            if isinstance(b, str):
+                assert a == b, k
+            elif b != b:
+                assert a != a, k
+            else:
+                assert abs(a - b) <= tol * abs(b), (k, a, b)
+
+
 class TestCheckpointResume:
     def _cfg(self, ckpt_dir: str) -> ExperimentConfig:
         return ExperimentConfig(duration_s=60.0).with_engine(
@@ -174,6 +231,26 @@ class TestCheckpointResume:
             checkpoint_every_s=20.0)
         with pytest.raises(ValueError, match="refusing to resume"):
             run_experiment(other)
+
+    def test_resume_refuses_unstacked_window_sums(self, tmp_path):
+        """A checkpoint holding the window sums as eleven separate
+        arrays (`mw_cnt` ... `res_fbusy`), not the stacked `mw` and
+        `res`, is refused rather than misread."""
+        from repro.checkpoint import store
+        ckpt = str(tmp_path / "ckpt")
+        cfg = self._cfg(ckpt)
+        state = dict(FleetEngine(cfg).state)
+        for name, rows in (("mw", ("cnt", "wait", "iter", "cpuw", "sp",
+                                   "st", "comps")),
+                           ("res", ("busy", "idle", "gated", "fbusy"))):
+            stacked = state.pop(name)
+            state.update({f"{name}_{r}": v for r, v in zip(rows, stacked)})
+        state["macro"] = np.asarray(20, dtype=np.int64)
+        store.save(ckpt, 20, state,
+                   extra={"config": cfg.fingerprint(), "engine": "fleet",
+                          "backend": "numpy", "macro": 20})
+        with pytest.raises(KeyError, match="mw"):
+            run_experiment(cfg)
 
 
 class TestConfigPlumbing:
